@@ -58,6 +58,12 @@ def vandermonde(x: jax.Array, degree: int, basis: str = MONOMIAL) -> jax.Array:
     Powers are built by iterated multiplication, never ``pow`` — this is the
     same trick the paper's CUDA kernel uses and what the Pallas kernel mirrors.
     """
+    return jnp.stack(basis_columns(x, degree, basis), axis=-1)
+
+
+def basis_columns(x: jax.Array, degree: int,
+                  basis: str = MONOMIAL) -> list[jax.Array]:
+    """The columns of ``vandermonde``, each shaped like x."""
     if basis not in _BASES:
         raise ValueError(f"unknown basis {basis!r}; expected one of {_BASES}")
     if degree < 0:
@@ -71,7 +77,7 @@ def vandermonde(x: jax.Array, degree: int, basis: str = MONOMIAL) -> jax.Array:
     else:
         for _ in range(2, degree + 1):
             cols.append(2.0 * x * cols[-1] - cols[-2])
-    return jnp.stack(cols, axis=-1)
+    return cols
 
 
 @partial(jax.jit, static_argnames=("degree", "basis"))

@@ -33,14 +33,6 @@ from repro.core import fit as fit_lib
 from repro.core import moments as moments_lib
 from repro.core import solve as solve_lib
 
-try:  # jax >= 0.4.38 top-level export with the renamed replication check
-    _shard_map = jax.shard_map
-    _CHECK_KW = {"check_vma": False}
-except AttributeError:  # 0.4.37: experimental home, check_rep spelling
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _CHECK_KW = {"check_rep": False}
-
-
 def local_moments(x: jax.Array, y: jax.Array, degree: int, *,
                   basis: str = basis_lib.MONOMIAL,
                   weights: jax.Array | None = None,
@@ -231,9 +223,9 @@ def make_spec_executor(spec, mesh: jax.sharding.Mesh, *,
 
     # ------------------------------------------------------------ programs
     if search:
-        @partial(_shard_map, mesh=mesh,
+        @partial(jax.shard_map, mesh=mesh,
                  in_specs=(spec_in, spec_in, spec_in),
-                 out_specs=(spec_rep, spec_rep, spec_rep), **_CHECK_KW)
+                 out_specs=(spec_rep, spec_rep, spec_rep), check_vma=False)
         def _run(x, y, w):
             w = apply_decay(x, w)
             dom = shard_domain(x, w)
@@ -274,10 +266,10 @@ def make_spec_executor(spec, mesh: jax.sharding.Mesh, *,
             return poly, sweep, best
 
     elif spec.method == "irls":
-        @partial(_shard_map, mesh=mesh,
+        @partial(jax.shard_map, mesh=mesh,
                  in_specs=(spec_in, spec_in, spec_in),
                  out_specs=(spec_rep, spec_rep, spec_rep, spec_rep),
-                 **_CHECK_KW)
+                 check_vma=False)
         def _run(x, y, w):
             w = apply_decay(x, w)
             dom = shard_domain(x, w)
@@ -291,10 +283,10 @@ def make_spec_executor(spec, mesh: jax.sharding.Mesh, *,
     elif spec.method == "lspia":
         from repro.core import lspia as lspia_lib
 
-        @partial(_shard_map, mesh=mesh,
+        @partial(jax.shard_map, mesh=mesh,
                  in_specs=(spec_in, spec_in, spec_in),
                  out_specs=(spec_rep, spec_rep, spec_rep, spec_rep),
-                 **_CHECK_KW)
+                 check_vma=False)
         def _run(x, y, w):
             # the distributed surface already pays the O(m²) psum, so the
             # fixed point is reached by Richardson on the psum'd normal
@@ -317,9 +309,9 @@ def make_spec_executor(spec, mesh: jax.sharding.Mesh, *,
 
     else:
         # plain matricized LSE — the paper's algorithm, pod-scale
-        @partial(_shard_map, mesh=mesh,
+        @partial(jax.shard_map, mesh=mesh,
                  in_specs=(spec_in, spec_in, spec_in),
-                 out_specs=(spec_rep, spec_rep), **_CHECK_KW)
+                 out_specs=(spec_rep, spec_rep), check_vma=False)
         def _run(x, y, w):
             w = apply_decay(x, w)
             dom = shard_domain(x, w)

@@ -14,8 +14,9 @@ TPU-native adaptation of the paper's CUDA moment kernel (DESIGN.md §2):
 
 Three kernels live here:
 
-``moments_extended``          one series per (128, block_n) tile (the
-                              original layout; rows degree+2..127 are zero).
+``moments_extended``          one series per (128, block_n) MXU tile (the
+                              original layout; rows degree+2..127 are zero),
+                              ROW_BLOCK series per grid step.
 ``moments_packed_extended``   P = 128 // (degree+2) series per tile — the
                               packed layout below.
 ``fused_report_sums``         one streamed pass computing everything
@@ -92,6 +93,16 @@ from jax.experimental.pallas import tpu as pltpu
 
 K_PAD = 128          # fixed row count: degree + 2 <= 128
 DEFAULT_BLOCK_N = 4096
+# series per grid step of the plain and fused-report kernels: a data block's
+# second-to-last dim must be a multiple of 8 or the full batch (TPU tiling)
+ROW_BLOCK = 8
+# Precision of the f32 Gram products on the MXU.  DEFAULT rounds both
+# operands to bf16 once, and the error that leaves in a fit shrinks as the
+# series grows; HIGHEST (six bf16 passes) is f32-accurate at about five times
+# the MXU time.  Excess SSE over the f64 least-squares fit, degree 3 on TPU
+# v5e, DEFAULT / HIGHEST: one series of 2048 points 8.5e-4 / 1.8e-8, of 2^15
+# points 3.8e-5 / 2.2e-9, of 2^17 to 2^27 points <= 1.5e-6 / <= 3.8e-7.
+DEFAULT_PRECISION_MIN_N = 1 << 17
 
 # index layout of the fused-report sums vector (lane j of the (B, 128) out)
 SUM_W, SUM_Y, SUM_YY, SUM_F, SUM_FF, SUM_YF, SUM_SSE, N_SUMS = range(8)
@@ -100,6 +111,28 @@ SUM_W, SUM_Y, SUM_YY, SUM_F, SUM_FF, SUM_YF, SUM_SSE, N_SUMS = range(8)
 def packing_factor(degree: int) -> int:
     """How many independent series fit in one 128-sublane tile."""
     return K_PAD // (degree + 2)
+
+
+def gram_precision(n: int) -> jax.lax.Precision:
+    """MXU precision of the Gram products for series of n points a call."""
+    if n >= DEFAULT_PRECISION_MIN_N:
+        return jax.lax.Precision.DEFAULT
+    return jax.lax.Precision.HIGHEST
+
+
+def row_block(b: int) -> int:
+    """Rows per grid step of the plain and fused-report kernels for a batch
+    of b series: the whole batch below ROW_BLOCK, else ROW_BLOCK.  The
+    batch they take must be a multiple of it (pad with zero-weight rows)."""
+    return min(b, ROW_BLOCK)
+
+
+def _checked_row_block(b: int) -> int:
+    rb = row_block(b)
+    if b % rb:
+        raise ValueError(f"batch {b} must be <= {ROW_BLOCK} or a multiple "
+                         f"of {ROW_BLOCK} (pad with zero-weight rows)")
+    return rb
 
 
 def _accum_init(i, out_refs):
@@ -131,32 +164,33 @@ def _power_rows(x, y, degree):
 
 
 def _moments_kernel(x_ref, y_ref, w_ref, g_ref, *maybe_c, degree: int,
-                    accum_dtype):
-    """One (batch, block) grid step: G[b] += (W·w) Wᵀ for this tile."""
+                    accum_dtype, precision):
+    """One (row-block, n-block) grid step: G[r] += (W_r·w_r) W_rᵀ for each
+    series r of the (rb, block_n) tile."""
     c_ref = maybe_c[0] if maybe_c else None
     i = pl.program_id(1)
     _accum_init(i, (g_ref,) + ((c_ref,) if c_ref is not None else ()))
 
-    x = x_ref[...].astype(accum_dtype)   # (1, block_n)
-    y = y_ref[...].astype(accum_dtype)   # (1, block_n)
-    w = w_ref[...].astype(accum_dtype)   # (1, block_n)
+    x = x_ref[...].astype(accum_dtype)   # (rb, block_n)
+    y = y_ref[...].astype(accum_dtype)
+    w = w_ref[...].astype(accum_dtype)
 
-    # Build W rows by the iterated-multiply power ladder (paper's trick).
-    wmat = _power_rows(x[0], y[0], degree)                   # (deg+2, bn)
-    pad = K_PAD - (degree + 2)
-    if pad:
-        wmat = jnp.concatenate(
-            [wmat, jnp.zeros((pad, wmat.shape[1]), accum_dtype)], axis=0)
+    updates = []
+    for r in range(x.shape[0]):
+        # Build W rows by the iterated-multiply power ladder (paper's trick).
+        wmat = _power_rows(x[r], y[r], degree)               # (deg+2, bn)
+        pad = K_PAD - (degree + 2)
+        if pad:
+            wmat = jnp.concatenate(
+                [wmat, jnp.zeros((pad, wmat.shape[1]), accum_dtype)], axis=0)
+        # MXU: (128, bn) @ (bn, 128), f32 accumulation; one side weighted.
+        updates.append(jax.lax.dot_general(
+            wmat * w[r], wmat, (((1,), (1,)), ((), ())),
+            precision=precision, preferred_element_type=accum_dtype))
+    _accum_add(jnp.stack(updates), g_ref, c_ref)
 
-    lhs = wmat * w                                           # weight one side
-    # MXU: (128, bn) @ (bn, 128), f32 accumulation.
-    update = jax.lax.dot_general(
-        lhs, wmat, (((1,), (1,)), ((), ())),
-        preferred_element_type=accum_dtype)[None]
-    _accum_add(update, g_ref, c_ref)
 
-
-def _packed_tile_update(x, y, w, degree: int, accum_dtype):
+def _packed_tile_update(x, y, w, degree: int, accum_dtype, precision):
     """The packed layout's (1, 128, 128) Gram contribution of one
     (P, block_n) tile — the ONE definition both the grid-streamed and the
     double-buffered kernels accumulate, so their results agree bitwise."""
@@ -166,37 +200,49 @@ def _packed_tile_update(x, y, w, degree: int, accum_dtype):
     p, bn = x.shape
     k = degree + 2
 
-    # (K, P, bn) power rows -> interleave to series-major (P*K, bn) so each
-    # series owns a contiguous sublane block (diagonal extraction below).
-    rows = _power_rows(x, y, degree)
-    wmat = jnp.swapaxes(rows, 0, 1).reshape(p * k, bn)
-    wfull = jnp.repeat(w, k, axis=0)                         # row p*K+j <- w[p]
+    # Series-major rows built one (1, bn) row at a time: row s*K + j holds
+    # series s's x^j (its y at j = K-1), so each series owns a contiguous
+    # sublane block (diagonal extraction below).  Interleaving the stacked
+    # (K, P, bn) power rows by swapaxes + reshape instead gave series 4-7,
+    # 12-15 and 20-23 of a tile wrong Gram blocks on TPU v5e at
+    # block_n <= 256 (correct from 1024 up, and in interpret mode).
+    wmat_rows, lhs_rows = [], []
+    for s in range(p):
+        xs, ys, ws = x[s:s + 1], y[s:s + 1], w[s:s + 1]
+        pows = [jnp.ones_like(xs)]
+        for _ in range(degree):
+            pows.append(pows[-1] * xs)
+        for row in pows + [ys]:
+            wmat_rows.append(row)
+            lhs_rows.append(row * ws)
     pad = K_PAD - p * k
     if pad:
         zpad = jnp.zeros((pad, bn), accum_dtype)
-        wmat = jnp.concatenate([wmat, zpad], axis=0)
-        wfull = jnp.concatenate([wfull, zpad], axis=0)
+        wmat_rows.append(zpad)
+        lhs_rows.append(zpad)
+    wmat = jnp.concatenate(wmat_rows, axis=0)
+    lhs = jnp.concatenate(lhs_rows, axis=0)
 
     return jax.lax.dot_general(
-        wmat * wfull, wmat, (((1,), (1,)), ((), ())),
-        preferred_element_type=accum_dtype)[None]
+        lhs, wmat, (((1,), (1,)), ((), ())),
+        precision=precision, preferred_element_type=accum_dtype)[None]
 
 
 def _packed_moments_kernel(x_ref, y_ref, w_ref, g_ref, *maybe_c, degree: int,
-                           accum_dtype):
+                           accum_dtype, precision):
     """One (group, block) grid step with P series packed into the sublanes."""
     c_ref = maybe_c[0] if maybe_c else None
     i = pl.program_id(1)
     _accum_init(i, (g_ref,) + ((c_ref,) if c_ref is not None else ()))
 
     update = _packed_tile_update(x_ref[0], y_ref[0], w_ref[0], degree,
-                                 accum_dtype)
+                                 accum_dtype, precision)
     _accum_add(update, g_ref, c_ref)
 
 
 def _packed_moments_db_kernel(x_hbm, y_hbm, w_hbm, g_ref, *maybe_c,
-                              degree: int, accum_dtype, block_n: int,
-                              n_blocks: int, nbuf: int, p: int):
+                              degree: int, accum_dtype, precision,
+                              block_n: int, n_blocks: int, nbuf: int, p: int):
     """One grid step per GROUP; the n-block loop runs in-kernel over an
     ``nbuf``-slot VMEM ring with explicit async copies: block k+1's three
     DMAs are in flight while block k's matmul runs on the MXU."""
@@ -233,7 +279,7 @@ def _packed_moments_db_kernel(x_hbm, y_hbm, w_hbm, g_ref, *maybe_c,
             for d in dmas(slot, i):                # ...while block k lands
                 d.wait()
             update = _packed_tile_update(xs[slot], ys[slot], ws[slot],
-                                         degree, accum_dtype)
+                                         degree, accum_dtype, precision)
             _accum_add(update, g_ref, c_ref)
             return 0
 
@@ -254,24 +300,24 @@ def _fused_report_kernel(x_ref, y_ref, w_ref, coef_ref, o_ref, *, degree: int,
     i = pl.program_id(1)
     _accum_init(i, (o_ref,))
 
-    x = x_ref[...].astype(accum_dtype)       # (1, block_n)
+    x = x_ref[...].astype(accum_dtype)       # (rb, block_n)
     y = y_ref[...].astype(accum_dtype)
     w = w_ref[...].astype(accum_dtype)
-    c = coef_ref[...].astype(accum_dtype)    # (1, 128): coeffs then zero pad
+    c = coef_ref[...].astype(accum_dtype)    # (rb, 128): coeffs then zero pad
 
     # Horner evaluation — same O(m) ladder as basis.evaluate, in-register.
-    f = jnp.full_like(x, c[0, degree])
+    f = jnp.broadcast_to(c[:, degree:degree + 1], x.shape)
     for k in range(degree - 1, -1, -1):
-        f = f * x + c[0, k]
+        f = f * x + c[:, k:k + 1]
     e = y - f
 
-    sums = (jnp.sum(w), jnp.sum(w * y), jnp.sum(w * y * y),
-            jnp.sum(w * f), jnp.sum(w * f * f), jnp.sum(w * y * f),
-            jnp.sum(w * e * e))
+    sums = (w, w * y, w * y * y, w * f, w * f * f, w * y * f, w * e * e)
     lane = jax.lax.broadcasted_iota(jnp.int32, (1, K_PAD), 1)
-    update = jnp.zeros((1, K_PAD), accum_dtype)
+    update = jnp.zeros(o_ref.shape, accum_dtype)
     for j, s in enumerate(sums):
-        update = update + jnp.where(lane == j, s, jnp.zeros((), accum_dtype))
+        update = update + jnp.where(lane == j, jnp.sum(s, axis=1,
+                                                       keepdims=True),
+                                    jnp.zeros((), accum_dtype))
     o_ref[...] += update
 
 
@@ -300,8 +346,9 @@ def moments_extended(x: jax.Array, y: jax.Array, weights: jax.Array, *,
                      interpret: bool = False) -> jax.Array:
     """Raw kernel output: (B, K_PAD, K_PAD) extended Gram per batch row.
 
-    x, y, weights: (B, n) with n % block_n == 0 (ops.py handles padding —
-    padded tail carries weight 0 so it contributes nothing).
+    x, y, weights: (B, n) with n % block_n == 0 and B <= ROW_BLOCK or a
+    multiple of it (ops.py handles padding — padded tail points and rows
+    carry weight 0 so they contribute nothing).
     """
     if x.ndim != 2:
         raise ValueError("moments_extended expects (B, n) inputs")
@@ -310,12 +357,14 @@ def moments_extended(x: jax.Array, y: jax.Array, weights: jax.Array, *,
         raise ValueError(f"n={n} must be a multiple of block_n={block_n}")
     if degree + 2 > K_PAD:
         raise ValueError(f"degree {degree} too large for K_PAD={K_PAD}")
+    rb = _checked_row_block(b)
 
     kernel_fn = functools.partial(_moments_kernel, degree=degree,
-                                  accum_dtype=accum_dtype)
-    in_spec = pl.BlockSpec((1, block_n), lambda bi, ni: (bi, ni))
-    out_spec = pl.BlockSpec((1, K_PAD, K_PAD), lambda bi, ni: (bi, 0, 0))
-    return _moments_call(kernel_fn, (b, n // block_n), [in_spec] * 3,
+                                  accum_dtype=accum_dtype,
+                                  precision=gram_precision(n))
+    in_spec = pl.BlockSpec((rb, block_n), lambda bi, ni: (bi, ni))
+    out_spec = pl.BlockSpec((rb, K_PAD, K_PAD), lambda bi, ni: (bi, 0, 0))
+    return _moments_call(kernel_fn, (b // rb, n // block_n), [in_spec] * 3,
                          out_spec, b, compensated=compensated,
                          accum_dtype=accum_dtype, interpret=interpret,
                          args=(x, y, weights))
@@ -357,10 +406,11 @@ def moments_packed_extended(x: jax.Array, y: jax.Array, weights: jax.Array, *,
         n_blocks = n // block_n
         kernel_fn = functools.partial(
             _packed_moments_db_kernel, degree=degree,
-            accum_dtype=accum_dtype, block_n=block_n,
+            accum_dtype=accum_dtype, precision=gram_precision(n),
+            block_n=block_n,
             n_blocks=n_blocks, nbuf=min(nbuf, n_blocks) if n_blocks > 1
             else 2, p=p)
-        in_specs = [pl.BlockSpec(memory_space=pltpu.ANY)] * 3
+        in_specs = [pl.BlockSpec(memory_space=pl.ANY)] * 3
         out_spec = pl.BlockSpec((1, K_PAD, K_PAD), lambda gi: (gi, 0, 0))
         return _moments_call(kernel_fn, (g,), in_specs, out_spec, g,
                              compensated=compensated,
@@ -368,7 +418,8 @@ def moments_packed_extended(x: jax.Array, y: jax.Array, weights: jax.Array, *,
                              args=(x, y, weights))
 
     kernel_fn = functools.partial(_packed_moments_kernel, degree=degree,
-                                  accum_dtype=accum_dtype)
+                                  accum_dtype=accum_dtype,
+                                  precision=gram_precision(n))
     in_spec = pl.BlockSpec((1, p, block_n), lambda gi, ni: (gi, 0, ni))
     out_spec = pl.BlockSpec((1, K_PAD, K_PAD), lambda gi, ni: (gi, 0, 0))
     return _moments_call(kernel_fn, (g, n // block_n), [in_spec] * 3,
@@ -399,6 +450,7 @@ def fused_report_sums(x: jax.Array, y: jax.Array, weights: jax.Array,
     Returns (B, K_PAD) where lanes SUM_W..SUM_SSE hold
     [Σw, Σwy, Σwy², Σwf, Σwf², Σwyf, Σw(y-f)²] and the rest are zero.
     ``coeffs``: (B, K_PAD) monomial coefficients, zero-padded past degree.
+    B <= ROW_BLOCK or a multiple of it (ops.py pads with zero-weight rows).
     Everything ``fit_report`` derives (SSE, R) follows from these sums with
     O(B) work — no (B, n) fitted/residual arrays ever touch HBM.
     """
@@ -407,17 +459,17 @@ def fused_report_sums(x: jax.Array, y: jax.Array, weights: jax.Array,
     b, n = x.shape
     if n % block_n:
         raise ValueError(f"n={n} must be a multiple of block_n={block_n}")
+    rb = _checked_row_block(b)
 
     kernel_fn = functools.partial(_fused_report_kernel, degree=degree,
                                   accum_dtype=accum_dtype)
-    data_spec = pl.BlockSpec((1, block_n), lambda bi, ni: (bi, ni))
-    coef_spec = pl.BlockSpec((1, K_PAD), lambda bi, ni: (bi, 0))
-    out_spec = pl.BlockSpec((1, K_PAD), lambda bi, ni: (bi, 0))
+    data_spec = pl.BlockSpec((rb, block_n), lambda bi, ni: (bi, ni))
+    row_spec = pl.BlockSpec((rb, K_PAD), lambda bi, ni: (bi, 0))
     return pl.pallas_call(
         kernel_fn,
-        grid=(b, n // block_n),
-        in_specs=[data_spec, data_spec, data_spec, coef_spec],
-        out_specs=out_spec,
+        grid=(b // rb, n // block_n),
+        in_specs=[data_spec, data_spec, data_spec, row_spec],
+        out_specs=row_spec,
         out_shape=jax.ShapeDtypeStruct((b, K_PAD), accum_dtype),
         interpret=interpret,
     )(x, y, weights, coeffs)

@@ -50,6 +50,16 @@ def _pad_tail(arrs, pad):
     return [jnp.pad(a, zpad) for a in arrs]
 
 
+def _pad_rows(arrs, multiple):
+    """Pad the leading (series) axis to a multiple with zero rows; callers
+    pad the weights with zeros too, so padded series contribute nothing."""
+    pad = (-arrs[0].shape[0]) % multiple
+    if not pad:
+        return arrs
+    zpad = [(0, pad)] + [(0, 0)] * (arrs[0].ndim - 1)
+    return [jnp.pad(a, zpad) for a in arrs]
+
+
 def _true_count(weights, b, n, dtype):
     """Number of contributing points per series (not Σw — see module doc)."""
     if weights is None:
@@ -113,24 +123,20 @@ def moments(x: jax.Array, y: jax.Array, degree: int, *,
     # zero weight ⇒ padded tail contributes nothing
 
     if use_packed:
-        bpad = (-b) % pfac
-        if bpad:
-            zrow = [(0, bpad), (0, 0)]
-            x = jnp.pad(x, zrow)
-            y = jnp.pad(y, zrow)
-            w = jnp.pad(w, zrow)   # zero-weight tail series: exact-zero blocks
-        groups = (b + bpad) // pfac
-        shape = (groups, pfac, x.shape[-1])
+        # zero-weight tail series: exact-zero blocks
+        x, y, w = _pad_rows([x, y, w], pfac)
+        shape = (x.shape[0] // pfac, pfac, x.shape[-1])
         gp = kernel.moments_packed_extended(
             x.reshape(shape), y.reshape(shape), w.reshape(shape),
             degree=degree, block_n=block_n, accum_dtype=accum_dtype,
             compensated=compensated, nbuf=nbuf, interpret=interpret)
         g = kernel.extract_packed(gp, degree)[:b]         # (b, m+2, m+2)
     else:
+        x, y, w = _pad_rows([x, y, w], kernel.row_block(b))
         g = kernel.moments_extended(x, y, w, degree=degree, block_n=block_n,
                                     accum_dtype=accum_dtype,
                                     compensated=compensated,
-                                    interpret=interpret)
+                                    interpret=interpret)[:b]
     m1 = degree + 1
     out = Moments(gram=g[:, :m1, :m1], vty=g[:, :m1, m1],
                   yty=g[:, m1, m1], count=count, weight_sum=weight_sum)
@@ -173,10 +179,11 @@ def fused_report_sums(x: jax.Array, y: jax.Array, coeffs: jax.Array, *,
     if block_n is None:
         block_n = _auto_block(n)
     xb, yb, wb = _pad_tail([xb, yb, wb], (-n) % block_n)
+    xb, yb, wb, cb = _pad_rows([xb, yb, wb, cb], kernel.row_block(b))
 
     sums = kernel.fused_report_sums(
         xb, yb, wb, cb.astype(accum_dtype), degree=degree, block_n=block_n,
-        accum_dtype=accum_dtype, interpret=interpret)
+        accum_dtype=accum_dtype, interpret=interpret)[:b]
     names = ("sw", "sy", "syy", "sf", "sff", "syf", "sse")
     return {name: sums[:, j].reshape(batch)
             for j, name in enumerate(names)}

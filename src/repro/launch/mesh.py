@@ -6,27 +6,23 @@ device state. The dry-run sets XLA_FLAGS before importing anything.
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.4.38; older releases have no explicit axis types
-    from jax.sharding import AxisType
-    _AXIS_KW = lambda n: {"axis_types": (AxisType.Auto,) * n}
-except ImportError:
-    AxisType = None
-    _AXIS_KW = lambda n: {}
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16×16 single pod (256 chips) or 2×16×16 two pods (512 chips)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_AXIS_KW(len(axes)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(data: int | None = None, model: int = 1):
     """Small mesh over whatever devices exist (tests / local runs)."""
     n = len(jax.devices())
     data = data or (n // model)
-    return jax.make_mesh((data, model), ("data", "model"), **_AXIS_KW(2))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def required_devices(multi_pod: bool) -> int:

@@ -5,9 +5,8 @@ executables) with the *dynamic* half the bench harness needs:
 
 1. **Measured bandwidth** — a STREAM-style triad microbenchmark run on the
    actual backend at import-of-first-use, so ceilings are anchored to the
-   machine the numbers were produced on, not a hardware spec sheet.  Falls
-   back to the hardware model (``roofline.HBM_BW``) when measurement is
-   unavailable (and says so in the provenance).
+   machine the numbers were produced on, not a hardware spec sheet.  A
+   measurement that fails raises; no peak is assumed in its place.
 2. **Memory-bound ceilings** for the moment/report passes.  The complexity
    analysis behind the paper (arXiv:cs/0308023) makes the moment pass
    provably memory-bound: every point is read exactly once (x, y and
@@ -29,8 +28,6 @@ from __future__ import annotations
 
 import dataclasses
 import time
-
-from repro.launch import roofline
 
 DTYPE_BYTES = 4                   # the fit stack streams f32 series
 
@@ -57,36 +54,33 @@ def measure_bandwidth(*, n_mb: int = 64, reps: int = 5, iters: int = 4,
 
     Moves 3 arrays per call (read b, read c, write a); min-of-reps timing
     gives the *max* sustained bandwidth — the right anchor for a ceiling.
-    Cached per backend.  Falls back to the ``roofline`` hardware model
-    (TPU v5e HBM) if the measurement cannot run or produces nonsense.
+    Cached per backend.  Raises if the measurement cannot run or produces
+    nonsense.
     """
     import jax
 
     bk = backend or jax.default_backend()
     if not force and bk in _BW_CACHE:
         return _BW_CACHE[bk]
-    try:
-        import jax.numpy as jnp
+    import jax.numpy as jnp
 
-        n = n_mb * (1 << 20) // DTYPE_BYTES
-        b = jnp.arange(n, dtype=jnp.float32)
-        c = jnp.ones((n,), jnp.float32)
-        triad = jax.jit(lambda b, c: b + 0.5 * c)
-        jax.block_until_ready(triad(b, c))            # compile
-        best = float("inf")
-        for _ in range(reps):
-            t0 = time.perf_counter()
-            out = None
-            for _ in range(iters):
-                out = triad(b, c)
-            jax.block_until_ready(out)
-            best = min(best, (time.perf_counter() - t0) / iters)
-        gbps = 3 * n * DTYPE_BYTES / best / 1e9
-        if not (0.1 < gbps < 1e5):                    # nonsense guard
-            raise ValueError(f"implausible bandwidth {gbps} GB/s")
-        bw = Bandwidth(gbps=gbps, source="measured", backend=bk)
-    except Exception:  # noqa: BLE001 — fall back to the hardware model
-        bw = Bandwidth(gbps=roofline.HBM_BW / 1e9, source="model", backend=bk)
+    n = n_mb * (1 << 20) // DTYPE_BYTES
+    b = jnp.arange(n, dtype=jnp.float32)
+    c = jnp.ones((n,), jnp.float32)
+    triad = jax.jit(lambda b, c: b + 0.5 * c)
+    jax.block_until_ready(triad(b, c))            # compile
+    best = float("inf")
+    for _ in range(reps):
+        t0 = time.perf_counter()
+        out = None
+        for _ in range(iters):
+            out = triad(b, c)
+        jax.block_until_ready(out)
+        best = min(best, (time.perf_counter() - t0) / iters)
+    gbps = 3 * n * DTYPE_BYTES / best / 1e9
+    if not (0.1 < gbps < 1e5):                    # nonsense guard
+        raise ValueError(f"implausible bandwidth {gbps} GB/s")
+    bw = Bandwidth(gbps=gbps, source="measured", backend=bk)
     _BW_CACHE[bk] = bw
     return bw
 

@@ -24,7 +24,8 @@ import jax
 import numpy as np
 
 
-def serve_fits(args) -> None:
+def serve_fits(args) -> list:
+    """Serve ``args.requests`` seeded fits; returns the finished requests."""
     from repro import obs as obs_lib
     from repro.serve import FitServeConfig, FitServeEngine
 
@@ -34,7 +35,7 @@ def serve_fits(args) -> None:
     obs = obs_lib.Observability.on() if args.obs else obs_lib.NULL_OBS
     engine = FitServeEngine(cfg, obs=obs)
 
-    rng = np.random.default_rng(7)
+    rng = np.random.default_rng(args.seed)
     coef = rng.normal(0, 1, args.degree + 1)
 
     def make_request():
@@ -70,6 +71,7 @@ def serve_fits(args) -> None:
               f"{snap['counters']['completed']} latency p50/p99 = "
               f"{lat.quantile(0.5):.0f}/{lat.quantile(0.99):.0f} steps")
         print(obs.metrics.render_prometheus(), end="")
+    return reqs
 
 
 def serve_fleet(args) -> None:
@@ -85,7 +87,7 @@ def serve_fleet(args) -> None:
     from repro.runtime.chaos import ChaosSchedule
     from repro.serve import FitServeConfig, FleetConfig, FitFleet
 
-    rng = np.random.default_rng(7)
+    rng = np.random.default_rng(args.seed)
     coef = rng.normal(0, 1, args.degree + 1)
     series = []
     for _ in range(args.requests):
@@ -228,12 +230,18 @@ def serve_tokens(args) -> None:
 
 
 def main(argv=None):
+    """Run one serving workload; ``--workload fits`` returns its finished
+    requests."""
+    from repro.launch.compile_cache import use_compile_cache
+
     ap = argparse.ArgumentParser()
     ap.add_argument("--workload", choices=("fits", "fleet", "tokens"),
                     default="fits")
     # per-workload defaults: fits churns cheap requests, tokens decodes
     ap.add_argument("--requests", type=int, default=None)
     ap.add_argument("--slots", type=int, default=None)
+    ap.add_argument("--seed", type=int, default=7,
+                    help="seed of the generated fit requests")
     # fit-serving knobs
     ap.add_argument("--degree", type=int, default=3)
     ap.add_argument("--buckets", type=int, nargs="+", default=[256, 2048])
@@ -268,10 +276,11 @@ def main(argv=None):
     ap.add_argument("--max-len", type=int, default=128)
     ap.add_argument("--max-new", type=int, default=24)
     args = ap.parse_args(argv)
+    use_compile_cache()
     if args.workload == "fits":
         args.requests = 200 if args.requests is None else args.requests
         args.slots = 8 if args.slots is None else args.slots
-        serve_fits(args)
+        return serve_fits(args)
     elif args.workload == "fleet":
         args.requests = 32 if args.requests is None else args.requests
         serve_fleet(args)

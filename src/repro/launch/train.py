@@ -19,6 +19,7 @@ import jax.numpy as jnp
 from repro import checkpoint, configs
 from repro.data import DataConfig, TokenPipeline
 from repro.launch import mesh as mesh_lib
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import get_model
 from repro.sharding import rules
 from repro.train import (AdamWConfig, LossCurveMonitor, TrainConfig,
@@ -54,6 +55,7 @@ def main(argv=None):
     ap.add_argument("--model-parallel", type=int, default=1)
     ap.add_argument("--target-loss", type=float, default=None)
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg, model, tc = build(args)
     mesh = mesh_lib.make_host_mesh(model=args.model_parallel)

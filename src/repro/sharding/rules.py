@@ -134,12 +134,7 @@ def constrain(x, *logical, overrides: dict | None = None):
     """
     import jax.numpy as jnp  # local: avoid cycle at module import
 
-    get_mesh = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_mesh is None:
-        # jax < 0.4.38: no abstract-mesh context API; constraints are
-        # best-effort there, and host tests run without a mesh anyway.
-        return x
-    mesh = get_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh is None or not mesh.axis_names:
         return x
     rules = dict(BASE_RULES)

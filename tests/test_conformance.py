@@ -23,7 +23,6 @@ import pytest
 
 from repro import core
 
-enable_x64 = getattr(jax, "enable_x64", jax.experimental.enable_x64)
 
 DEGREES = list(range(1, 10))
 # the conformance grid stays on a modest domain so numpy.polyfit (QR on the
@@ -102,7 +101,7 @@ def test_conformance_float32(degree):
 @pytest.mark.parametrize("degree", DEGREES)
 def test_conformance_float64(degree):
     x, y = _data(100 + degree, 256, degree)
-    with enable_x64(True):
+    with jax.enable_x64(True):
         for basis in (core.MONOMIAL, core.CHEBYSHEV):
             for normalize in (False, True):
                 _check_against_numpy(x, y, degree, jnp.float64,
@@ -136,7 +135,7 @@ def test_degree9_wide_domain_is_rescued():
     """ISSUE-3 acceptance: degree-9 on a wide un-normalized domain — pure
     GE normal equations exceed 1e-2 relative coefficient error; the
     condition-aware default routes around it and lands ≤ 1e-3."""
-    with enable_x64(True):
+    with jax.enable_x64(True):
         worst_ge, worst_auto = 0.0, 0.0
         for seed in (1, 7, 42):
             rng = np.random.default_rng(seed)

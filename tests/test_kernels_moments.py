@@ -1,5 +1,7 @@
 """Pallas moments kernel: allclose vs the pure-jnp oracle across shapes,
 degrees, dtypes, block sizes — plus hypothesis property sweeps."""
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -106,3 +108,22 @@ def test_kernel_end_to_end_fit(seed):
     b = core.polyfit(x, y, 3, use_kernel=False).coeffs
     np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                rtol=5e-3, atol=5e-3)
+
+
+@pytest.mark.parametrize("n, want", [(4096, "HIGHEST"),
+                                     (kernel.DEFAULT_PRECISION_MIN_N,
+                                      "DEFAULT")])
+@pytest.mark.parametrize("packed", [False, True])
+def test_gram_precision_follows_series_length(n, want, packed):
+    """Both moment kernels take HIGHEST on short series and DEFAULT from
+    DEFAULT_PRECISION_MIN_N points a call."""
+    if packed:
+        arg = jax.ShapeDtypeStruct((1, kernel.packing_factor(3), n),
+                                   jnp.float32)
+        fn = kernel.moments_packed_extended
+    else:
+        arg = jax.ShapeDtypeStruct((1, n), jnp.float32)
+        fn = kernel.moments_extended
+    text = str(jax.make_jaxpr(
+        lambda a, b, c: fn(a, b, c, degree=3, interpret=True))(arg, arg, arg))
+    assert set(re.findall(r"precision=\(Precision\.(\w+)", text)) == {want}

@@ -64,6 +64,15 @@ def test_packed_forced_vs_plain(deg):
     _assert_moments_close(mp, _jnp_moments(x, y, deg))
 
 
+@pytest.mark.parametrize("b", [3, 8, 12])
+def test_plain_batched_rows(b):
+    """The plain kernel takes ROW_BLOCK series per grid step; a batch past
+    it is padded with zero-weight rows that are sliced away."""
+    x, y = _data(30 + b, b, 700)
+    _assert_moments_close(ops.moments(x, y, 3, packing="plain"),
+                          _jnp_moments(x, y, 3))
+
+
 @pytest.mark.parametrize("deg", [1, 3])
 def test_packed_bf16_inputs_f32_accumulate(deg):
     x, y = _data(20 + deg, 9, 2048, jnp.bfloat16)
@@ -139,7 +148,8 @@ def test_polyfit_use_kernel_batched_packed():
                                rtol=5e-3, atol=5e-3)
 
 
-@pytest.mark.parametrize("b,n,deg", [(1, 777, 3), (5, 500, 2), (8, 1024, 5)])
+@pytest.mark.parametrize("b,n,deg", [(1, 777, 3), (5, 500, 2), (8, 1024, 5),
+                                     (12, 300, 3)])
 def test_fused_report_matches_fit_report(b, n, deg):
     rng = np.random.default_rng(b * 10 + deg)
     x = jnp.asarray(rng.uniform(-2, 2, (b, n)), jnp.float32)

@@ -8,20 +8,15 @@ paper's 128.1999 (paper's polyfit column: 129.6512 — their polyfit ran at a
 lower effective precision; in f64 both methods coincide, which we also
 assert, and in f32 they diverge in the 3rd-4th decimal as the paper shows).
 
-x64 is enabled per-test via the jax.experimental.enable_x64 context so the
-rest of the suite keeps default f32 semantics.
+x64 is enabled per-test via the jax.enable_x64 context so the rest of the
+suite keeps default f32 semantics.
 """
 import jax
-import jax.experimental
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro import core
-
-# jax >= 0.4.38 exposes the x64 context as jax.enable_x64; older releases
-# only have the jax.experimental one. Same context manager either way.
-enable_x64 = getattr(jax, "enable_x64", jax.experimental.enable_x64)
 
 X64 = [39.206, 29.74, 21.31, 12.087, 1.812, 0.001]
 Y64 = [751.912, 567.121, 403.746, 221.738, 18.8418, 1.88672]
@@ -43,7 +38,7 @@ def _data():
 
 @pytest.mark.parametrize("order", [1, 2, 3])
 def test_generated_coefficients_match_paper(order):
-    with enable_x64(True):
+    with jax.enable_x64(True):
         x, y = _data()
         poly = core.polyfit(x, y, order)          # paper-faithful path
         got = np.asarray(poly.coeffs)
@@ -54,7 +49,7 @@ def test_generated_coefficients_match_paper(order):
 def test_gauss_equals_qr_in_f64(order):
     """In f64 the normal-equation and QR solutions coincide — the paper's
     accuracy gap is a precision artifact, which is itself informative."""
-    with enable_x64(True):
+    with jax.enable_x64(True):
         x, y = _data()
         a = np.asarray(core.polyfit(x, y, order).coeffs)
         b = np.asarray(
@@ -63,7 +58,7 @@ def test_gauss_equals_qr_in_f64(order):
 
 
 def test_order3_sse_matches_paper():
-    with enable_x64(True):
+    with jax.enable_x64(True):
         x, y = _data()
         poly = core.polyfit(x, y, 3)
         rep = core.fit_report(poly, x, y)
@@ -74,14 +69,14 @@ def test_order3_fitted_values_match_table_v():
     """Paper's Table V f(x) column was computed with their lower-precision
     coefficients; agreement holds to ~1e-2 absolute (4-5 significant
     digits), consistent with their printed rounding."""
-    with enable_x64(True):
+    with jax.enable_x64(True):
         x, y = _data()
         fitted = np.asarray(core.polyfit(x, y, 3)(x))
     np.testing.assert_allclose(fitted, PAPER_FITTED_ORDER3, atol=2e-2)
 
 
 def test_correlation_coefficient_high():
-    with enable_x64(True):
+    with jax.enable_x64(True):
         x, y = _data()
         for order in (1, 2, 3):
             rep = core.fit_report(core.polyfit(x, y, order), x, y)
@@ -103,7 +98,7 @@ def test_f32_reproduces_papers_precision_gap():
 
 def test_power_sum_hankel_identity():
     """A == VᵀV and B == Vᵀy: the matricization is exact."""
-    with enable_x64(True):
+    with jax.enable_x64(True):
         x, y = _data()
         m = core.gram_moments(x, y, 3)
         s = core.power_sums(x, 3)
@@ -115,9 +110,32 @@ def test_power_sum_hankel_identity():
             rtol=1e-12)
 
 
+@pytest.mark.parametrize("basis", ["monomial", "chebyshev"])
+@pytest.mark.parametrize("weighted", [False, True])
+def test_gram_from_basis_sums_is_vtwv(basis, weighted):
+    """The basis-sum Gram equals the explicit VᵀWV, Vᵀ(w·y) and Σw·y² over
+    the last axis of a batch."""
+    with jax.enable_x64(True):
+        rng = np.random.default_rng(3)
+        x = rng.uniform(-1.0, 1.0, (3, 200))
+        y = rng.normal(size=(3, 200))
+        w = rng.uniform(0.0, 2.0, (3, 200)) if weighted else np.ones((3, 200))
+        m = core.gram_moments(jnp.asarray(x), jnp.asarray(y), 5, basis=basis,
+                              weights=jnp.asarray(w) if weighted else None)
+        v = np.asarray(core.vandermonde(jnp.asarray(x), 5, basis))
+        np.testing.assert_allclose(
+            np.asarray(m.gram), np.einsum("bnj,bn,bnk->bjk", v, w, v),
+            rtol=1e-12, atol=1e-12)
+        np.testing.assert_allclose(
+            np.asarray(m.vty), np.einsum("bnj,bn->bj", v, w * y),
+            rtol=1e-12, atol=1e-12)
+        np.testing.assert_allclose(np.asarray(m.yty),
+                                   np.sum(w * y * y, axis=-1), rtol=1e-12)
+
+
 def test_sse_from_moments_identity():
     """Σe² computed from sufficient statistics alone (no data pass)."""
-    with enable_x64(True):
+    with jax.enable_x64(True):
         x, y = _data()
         poly = core.polyfit(x, y, 3)
         m = core.gram_moments(x, y, 3)
@@ -129,7 +147,7 @@ def test_sse_from_moments_identity():
 def test_normalized_fit_recovers_raw_coefficients():
     """Beyond-paper hardened path (x→[-1,1]) converts back to the same raw
     monomial coefficients."""
-    with enable_x64(True):
+    with jax.enable_x64(True):
         x, y = _data()
         raw = np.asarray(core.polyfit(x, y, 3).coeffs)
         norm = np.asarray(core.polyfit(x, y, 3, normalize=True)

@@ -23,7 +23,6 @@ from repro import core, engine, select
 from repro.core import streaming
 from repro.select import criteria, crossval
 
-enable_x64 = getattr(jax, "enable_x64", jax.experimental.enable_x64)
 
 settings.register_profile("select", deadline=None, max_examples=20)
 settings.load_profile("select")
@@ -79,7 +78,7 @@ def test_truncated_maxdegree_moments_match_direct_fit(degree, chebyshev,
     if chebyshev and engine_name == "kernel":
         return  # the Pallas kernels are monomial-only (validated centrally)
     dtype = jnp.float64 if mode == "f64_reference" else jnp.float32
-    ctx = enable_x64(True) if mode == "f64_reference" else None
+    ctx = jax.enable_x64(True) if mode == "f64_reference" else None
 
     rng = np.random.default_rng(1000 + degree)
     n = 160
@@ -173,7 +172,7 @@ def test_cv_scores_match_explicit_heldout_refits():
     tolerance: for each fold, refit the complement FROM THE RAW DATA at
     every degree and score the held-out points directly."""
     k, max_deg, n = 4, 6, 240
-    with enable_x64(True):
+    with jax.enable_x64(True):
         rng = np.random.default_rng(7)
         x = rng.uniform(-1.0, 1.0, n)
         y = (np.polyval([0.9, 0.3, -1.0, 0.5], x)
