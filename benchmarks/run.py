@@ -640,7 +640,7 @@ def bench_obs_overhead(quick: bool):
     """The observability tax (PR-9): the serve_fit ragged trace served
     twice by the same engine config — once with the default ``NULL_OBS``
     recorders, once with ``Observability.on()`` (live metric registry +
-    trace spans on every request).  All instrumentation is host-side
+    host-clock queue-wait and latency histograms).  All instrumentation is host-side
     python outside the jitted executables, so the measured gap is pure
     recording cost.  derived = overhead %; --smoke asserts it stays
     under 5% (the "observability is free" invariant the README claims).
@@ -686,17 +686,17 @@ def bench_obs_overhead(quick: bool):
     for _ in range(reps):
         dt_null = min(dt_null, one_rep(eng_null))
         dt_on = min(dt_on, one_rep(eng_on))
-    # the enabled side really recorded: full trace chains + live metrics
+    # the enabled side really recorded: live metrics and latencies
     assert obs.metrics.counter("completed").value >= n_req * reps
-    assert obs.metrics.histogram("fit_latency_steps").count >= n_req * reps
-    assert any(e["name"] == "respond" for e in obs.tracer.events)
+    assert obs.metrics.histogram("queue_wait_ms").count >= n_req * reps
+    assert obs.metrics.histogram("fit_latency_ms").count >= n_req * reps
     ratio = dt_on / dt_null
     us = Timed(dt_on / n_req * 1e6, {"stat": "min_of_reps", "reps": reps,
                                      "iters": n_req, "warmup": 1})
     row("obs_overhead", us,
         f"overhead={(ratio - 1) * 100:+.2f}%;"
         f"null_us={dt_null / n_req * 1e6:.1f};"
-        f"events={len(obs.tracer.events)};n_req={n_req}", n_fits=1)
+        f"n_req={n_req}", n_fits=1)
     if SMOKE:
         assert ratio < 1.05, (
             f"obs-enabled serving is {ratio:.3f}x the null path — the "

@@ -65,11 +65,14 @@ def serve_fits(args) -> list:
     assert recompiles == 0, f"{recompiles} recompiles during steady state"
     if args.obs:
         snap = obs.metrics.snapshot()
-        lat = obs.metrics.histogram("fit_latency_steps")
+        wait = obs.metrics.histogram("queue_wait_ms")
+        lat = obs.metrics.histogram("fit_latency_ms")
         print(f"[serve-fits] obs: submitted="
               f"{snap['counters']['submitted']} completed="
-              f"{snap['counters']['completed']} latency p50/p99 = "
-              f"{lat.quantile(0.5):.0f}/{lat.quantile(0.99):.0f} steps")
+              f"{snap['counters']['completed']} queue wait p50/p99 = "
+              f"{wait.quantile(0.5):.1f}/{wait.quantile(0.99):.1f} ms, "
+              f"latency p50/p99 = {lat.quantile(0.5):.1f}/"
+              f"{lat.quantile(0.99):.1f} ms")
         print(obs.metrics.render_prometheus(), end="")
     return reqs
 

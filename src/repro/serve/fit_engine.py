@@ -36,11 +36,17 @@ is necessarily pool-wide — it is baked into the slots' running moments —
 and comes from ``FitServeConfig`` (or its ``spec=``).
 
 The host loop is deliberately synchronous/deterministic — the scheduling
-substrate an async front-end would wrap.
+substrate an async front-end would wrap.  Each step writes spans on the
+JAX profiler's clock, the clock of the device trace: ``fit_engine.step``
+and, per bucket, ``fit_engine.pack`` → ``put`` → ``launch`` →
+``collect``.  With no profiler session active a span costs the entry and
+exit of an inactive object; under ``jax.profiler.trace`` each idle gap of
+the chip lines up with the host phase that kept it waiting.
 """
 from __future__ import annotations
 
 import dataclasses
+import time
 from typing import Any
 
 import jax
@@ -56,6 +62,8 @@ from repro.core import moments as moments_lib
 from repro.core import robust as robust_lib
 from repro.core import solve as solve_lib
 from repro.core import streaming
+
+_span = jax.profiler.TraceAnnotation
 
 
 @dataclasses.dataclass
@@ -514,9 +522,11 @@ class FitServeEngine:
         self._m_completed = self.obs.metrics.counter("completed")
         self._g_queue = self.obs.metrics.gauge("queue_depth")
         self._h_points = self.obs.metrics.histogram("points_per_fit")
-        self._h_latency = self.obs.metrics.histogram("fit_latency_steps")
+        # host-clock latencies, recorded only when obs is enabled
+        self._h_queue_wait = self.obs.metrics.histogram("queue_wait_ms")
+        self._h_latency = self.obs.metrics.histogram("fit_latency_ms")
+        self._submit_t: dict[int, float] = {}
         self._step_no = 0
-        self._admit_step: dict[int, int] = {}
         if tuple(sorted(cfg.buckets)) != tuple(cfg.buckets):
             raise ValueError(f"buckets must ascend: {cfg.buckets}")
         specs = self.pool_specs = derive_pool_specs(cfg)
@@ -535,7 +545,12 @@ class FitServeEngine:
         self.buckets = [_Bucket(w, cfg.n_slots, self) for w in cfg.buckets]
         self._uid = 0
         self.fits_done = 0
+        # per dispatch of ingest/ingest_solve: live points, active slots,
+        # slots sent, and lanes sent (slots × width)
         self.points_ingested = 0
+        self.slots_active = 0
+        self.slots_dispatched = 0
+        self.lanes_dispatched = 0
         self._solve = make_spec_solve(self.spec.max_degree)
         self._sweep = make_spec_sweep(self.spec.max_degree)
 
@@ -566,8 +581,8 @@ class FitServeEngine:
         req = FitRequest(self._uid, x, y, spec=rspec, auto=auto)
         self._uid += 1
         self._m_submitted.inc()
-        self.obs.tracer.instant(req.uid, "submit", self._step_no,
-                                n=req.n, auto=bool(auto))
+        if self.obs.enabled:
+            self._submit_t[req.uid] = time.perf_counter()
         for b in self.buckets[:-1]:
             if req.n <= b.width:
                 b.queue.append(req)
@@ -624,23 +639,42 @@ class FitServeEngine:
 
     # ----------------------------------------------------------------- run
     def _step_bucket(self, b: _Bucket) -> None:
-        # admit: fill free slots from this bucket's queue
+        with _span("fit_engine.pack", bucket=b.width):
+            packed = self._pack(b)
+        if packed is None:
+            return
+        host_args, ready = packed
+        with _span("fit_engine.put", bucket=b.width):
+            args = tuple(jnp.asarray(a) for a in host_args)
+        with _span("fit_engine.launch", bucket=b.width):
+            if ready:
+                b.state, fused = b.ingest_solve(b.state, *args)
+            else:
+                b.state = b.ingest(b.state, *args)
+        if ready:
+            with _span("fit_engine.collect", bucket=b.width):
+                self._collect(b, ready, fused)
+
+    def _pack(self, b: _Bucket):
+        """Admit from the queue and fill the host arrays of one dispatch:
+        ``(arrays, ready slots)``, or None when no slot is active."""
         for slot, req in enumerate(b.slot_req):
             if req is None and b.queue:
-                b.slot_req[slot] = b.queue.pop(0)
+                req = b.slot_req[slot] = b.queue.pop(0)
                 b.slot_pos[slot] = 0
                 b.reset[slot] = True
                 if self.obs.enabled:
-                    uid = b.slot_req[slot].uid
-                    self._admit_step[uid] = self._step_no
-                    self.obs.tracer.instant(uid, "admit", self._step_no,
-                                            bucket=b.width, slot=slot)
-                    self.obs.tracer.begin(uid, "serve", self._step_no)
+                    self._h_queue_wait.observe(
+                        (time.perf_counter() - self._submit_t[req.uid])
+                        * 1e3)
         active = [s for s, r in enumerate(b.slot_req) if r is not None]
         if not active:
-            return
+            return None
 
         n_slots, w = len(b.slot_req), b.width
+        self.slots_active += len(active)
+        self.slots_dispatched += n_slots
+        self.lanes_dispatched += n_slots * w
         xh = np.zeros((n_slots, w), np.float32)
         yh = np.zeros((n_slots, w), np.float32)
         wh = np.zeros((n_slots, w), np.float32)
@@ -671,13 +705,10 @@ class FitServeEngine:
         # between accumulate and solve — and the plain ingest on
         # mid-series steps, where a solve would be wasted work
         ready = [s for s in active if b.slot_pos[s] >= b.slot_req[s].n]
-        args = (jnp.asarray(xh), jnp.asarray(yh), jnp.asarray(wh),
-                jnp.asarray(keep), jnp.asarray(rmask),
-                jnp.asarray(loss_id), jnp.asarray(cval))
-        if not ready:
-            b.state = b.ingest(b.state, *args)
-            return
-        b.state, fused = b.ingest_solve(b.state, *args)
+        return (xh, yh, wh, keep, rmask, loss_id, cval), ready
+
+    def _collect(self, b: _Bucket, ready: list[int], fused) -> None:
+        """Bring the ready slots' answers to the host and free the slots."""
         # group ready slots by their request's spec: the default fixed
         # spec is already solved (fused above); every other DISTINCT spec
         # gets one compiled solve for its whole group
@@ -709,20 +740,18 @@ class FitServeEngine:
         self._m_completed.inc()
         self._h_points.observe(req.n)
         if self.obs.enabled:
-            t0 = self._admit_step.pop(req.uid, self._step_no)
-            self._h_latency.observe(self._step_no - t0)
-            self.obs.tracer.end(req.uid, "serve", self._step_no)
-            self.obs.tracer.instant(req.uid, "respond", self._step_no,
-                                    steps=self._step_no - t0)
+            self._h_latency.observe(
+                (time.perf_counter() - self._submit_t.pop(req.uid)) * 1e3)
 
     def step(self) -> None:
         """One engine iteration: admit + one compiled fused ingest+solve
         per non-empty bucket (+ one compiled solve per distinct ready
         NON-default spec)."""
         self._step_no += 1
-        for b in self.buckets:
-            self._step_bucket(b)
-        self._g_queue.set(sum(len(b.queue) for b in self.buckets))
+        with _span("fit_engine.step", step=self._step_no):
+            for b in self.buckets:
+                self._step_bucket(b)
+            self._g_queue.set(sum(len(b.queue) for b in self.buckets))
 
     def run(self, max_steps: int = 1_000_000) -> None:
         """Drive until every queued request is served (or max_steps)."""
