@@ -35,6 +35,16 @@ accumulation side (basis, engine path, decay, pinned domain, max degree)
 is necessarily pool-wide — it is baked into the slots' running moments —
 and comes from ``FitServeConfig`` (or its ``spec=``).
 
+Each bucket dispatch moves one array each way: ``_pack`` writes the
+chunk's x, y and weights and the per-slot keep/IRLS vectors into ONE
+float32 host buffer, copied to the device in one call and split inside
+the compiled step (``split_step_buffer``), and the fused step returns the
+default spec's six answers as ONE packed array (``pack_solved``), copied
+back in one call and split on the host (``unpack_solved``).  A small
+host-device copy costs the same whatever its size, so the step pays that
+cost twice, not thirteen times; ``h2d_copies`` / ``d2h_copies`` count the
+copy calls.
+
 The host loop is deliberately synchronous/deterministic — the scheduling
 substrate an async front-end would wrap.  Each step writes spans on the
 JAX profiler's clock, the clock of the device trace: ``fit_engine.step``
@@ -378,6 +388,37 @@ def fill_fixed_result(req: FitRequest, spec, solved, s=None) -> None:
     req.done = True
 
 
+def split_step_buffer(buf, width: int):
+    """The layout of the ONE float32 buffer a bucket dispatch sends, shape
+    (n_slots, 3·width + 4): x, y and the weights (n_slots, width) each,
+    then the per-slot keep, rmask, loss_id and cval (n_slots,).  Loss ids
+    are small integers, exact in float32; the compiled step casts them
+    back to int32.  Static slices: views of a numpy buffer, which
+    ``FitServeEngine._pack`` fills through them, and fixed-shape slices
+    inside the compiled step."""
+    w = width
+    return (buf[:, :w], buf[:, w:2 * w], buf[:, 2 * w:3 * w], buf[:, 3 * w],
+            buf[:, 3 * w + 1], buf[:, 3 * w + 2], buf[:, 3 * w + 3])
+
+
+def pack_solved(solved):
+    """A fixed-degree solve's six outputs as ONE (n_slots, (d+1) + 5)
+    array in their common float dtype — columns coeffs, sse, r, count,
+    cond, fallback (0/1) — so one copy brings them all to the host."""
+    coeffs, sse, r, count, cond, fb = solved
+    dt = jnp.result_type(coeffs, sse, r, count, cond)
+    cols = [a.astype(dt)[:, None] for a in (sse, r, count, cond, fb)]
+    return jnp.concatenate([coeffs.astype(dt)] + cols, axis=1)
+
+
+def unpack_solved(packed: np.ndarray):
+    """Host-side inverse of ``pack_solved``: the six-tuple
+    ``fill_fixed_result`` takes."""
+    coeffs, rest = packed[:, :-5], packed[:, -5:]
+    sse, r, count, cond, fb = rest.T
+    return coeffs, sse, r, count, cond, fb != 0
+
+
 def auto_outputs(sw, r_ladder, count) -> dict:
     """Convert one ``make_spec_sweep`` output to host-side numpy once per
     solve (the per-request fill then just indexes)."""
@@ -430,7 +471,10 @@ class _Bucket:
         sweeps = pool.irls.stream_sweeps
 
         @jax.jit
-        def ingest(state, x, y, w, keep, rmask, loss_id, cval):
+        def ingest(state, buf):
+            x, y, w, keep, rmask, loss_id, cval = split_step_buffer(buf,
+                                                                    width)
+            loss_id = loss_id.astype(jnp.int32)
             # keep==0 wipes a slot's previous occupant inside the same
             # compiled step (count included: it restarts for the new series)
             m = state.moments
@@ -496,13 +540,14 @@ class _Bucket:
         # ticks.  The solve half is the same ``_spec_solve_from_state``
         # the standalone executable traces — non-default request specs
         # still go through ``FitServeEngine._solve`` on the returned
-        # state, unchanged.
+        # state, unchanged.  Its six answers leave as one packed array.
         fixed_spec = engine.fixed_spec
 
         @jax.jit
-        def ingest_solve(state, x, y, w, keep, rmask, loss_id, cval):
-            st = ingest(state, x, y, w, keep, rmask, loss_id, cval)
-            return st, _spec_solve_from_state(st, fixed_spec, degree)
+        def ingest_solve(state, buf):
+            st = ingest(state, buf)
+            return st, pack_solved(
+                _spec_solve_from_state(st, fixed_spec, degree))
 
         self.ingest_solve = ingest_solve
 
@@ -551,6 +596,10 @@ class FitServeEngine:
         self.slots_active = 0
         self.slots_dispatched = 0
         self.lanes_dispatched = 0
+        # host↔device copy calls the steps make: one each way per
+        # dispatch on the default path
+        self.h2d_copies = 0
+        self.d2h_copies = 0
         self._solve = make_spec_solve(self.spec.max_degree)
         self._sweep = make_spec_sweep(self.spec.max_degree)
 
@@ -643,21 +692,26 @@ class FitServeEngine:
             packed = self._pack(b)
         if packed is None:
             return
-        host_args, ready = packed
+        host_buf, ready = packed
         with _span("fit_engine.put", bucket=b.width):
-            args = tuple(jnp.asarray(a) for a in host_args)
+            # the whole dispatch's input in one host-to-device copy
+            buf = jnp.asarray(host_buf)
+            self.h2d_copies += 1
         with _span("fit_engine.launch", bucket=b.width):
             if ready:
-                b.state, fused = b.ingest_solve(b.state, *args)
+                b.state, fused = b.ingest_solve(b.state, buf)
             else:
-                b.state = b.ingest(b.state, *args)
+                b.state = b.ingest(b.state, buf)
         if ready:
             with _span("fit_engine.collect", bucket=b.width):
                 self._collect(b, ready, fused)
 
     def _pack(self, b: _Bucket):
-        """Admit from the queue and fill the host arrays of one dispatch:
-        ``(arrays, ready slots)``, or None when no slot is active."""
+        """Admit from the queue and fill the host buffer of one dispatch
+        (``split_step_buffer`` gives its layout): ``(buffer, ready
+        slots)``, or None when no slot is active.  The buffer is fresh
+        each step: on the CPU backend a device array may alias the numpy
+        memory it was made from."""
         for slot, req in enumerate(b.slot_req):
             if req is None and b.queue:
                 req = b.slot_req[slot] = b.queue.pop(0)
@@ -675,12 +729,9 @@ class FitServeEngine:
         self.slots_active += len(active)
         self.slots_dispatched += n_slots
         self.lanes_dispatched += n_slots * w
-        xh = np.zeros((n_slots, w), np.float32)
-        yh = np.zeros((n_slots, w), np.float32)
-        wh = np.zeros((n_slots, w), np.float32)
-        rmask = np.zeros(n_slots, np.float32)
-        loss_id = np.zeros(n_slots, np.int32)
-        cval = np.ones(n_slots, np.float32)
+        buf = np.zeros((n_slots, 3 * w + 4), np.float32)
+        xh, yh, wh, keep, rmask, loss_id, cval = split_step_buffer(buf, w)
+        cval[:] = 1.0
         for s in active:
             req = b.slot_req[s]
             lo = int(b.slot_pos[s])
@@ -696,7 +747,7 @@ class FitServeEngine:
                 loss_id[s] = robust_lib.LOSS_IDS[req.spec.irls.loss]
                 cval[s] = robust_lib.resolve_tuning(req.spec.irls.loss,
                                                     req.spec.irls.c)
-        keep = np.where(b.reset, 0.0, 1.0).astype(np.float32)
+        keep[:] = np.where(b.reset, 0.0, 1.0)
         b.reset[:] = False
         # readiness is host-known BEFORE dispatch (slot_pos already
         # advanced), so each step picks the cheapest executable: the
@@ -705,22 +756,28 @@ class FitServeEngine:
         # between accumulate and solve — and the plain ingest on
         # mid-series steps, where a solve would be wasted work
         ready = [s for s in active if b.slot_pos[s] >= b.slot_req[s].n]
-        return (xh, yh, wh, keep, rmask, loss_id, cval), ready
+        return buf, ready
 
     def _collect(self, b: _Bucket, ready: list[int], fused) -> None:
         """Bring the ready slots' answers to the host and free the slots."""
         # group ready slots by their request's spec: the default fixed
-        # spec is already solved (fused above); every other DISTINCT spec
-        # gets one compiled solve for its whole group
+        # spec is already solved (fused above) and its answers come back
+        # in ONE device-to-host copy of the packed array; every other
+        # DISTINCT spec gets one compiled solve for its whole group, and
+        # one copy per output
         fixed_groups: dict[Any, list[int]] = {}
         auto_groups: dict[Any, list[int]] = {}
         for s in ready:
             groups = (auto_groups if b.slot_req[s].auto else fixed_groups)
             groups.setdefault(b.slot_req[s].spec, []).append(s)
         for spec, slots in fixed_groups.items():
-            out = (fused if spec == self.fixed_spec
-                   else self._solve(b.state, spec))
-            solved = tuple(np.asarray(a) for a in out)
+            if spec == self.fixed_spec:
+                solved = unpack_solved(np.asarray(fused))
+                self.d2h_copies += 1
+            else:
+                solved = tuple(np.asarray(a)
+                               for a in self._solve(b.state, spec))
+                self.d2h_copies += len(solved)
             for s in slots:
                 req = b.slot_req[s]
                 fill_fixed_result(req, spec, solved, s)
@@ -728,6 +785,8 @@ class FitServeEngine:
                 self._done(req)
         for spec, slots in auto_groups.items():
             outs = auto_outputs(*self._sweep(b.state, spec))
+            # one copy per criterion row and per other output
+            self.d2h_copies += len(outs["scores"]) + len(outs) - 1
             crit = spec.degree.criterion or self.cfg.select_criterion
             for s in slots:
                 req = b.slot_req[s]

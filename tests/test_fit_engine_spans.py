@@ -144,3 +144,13 @@ def test_the_benchmarks_traced_run_takes_the_engines_spans():
     got = run_small("serve_overload", trace=True)
     assert got["correct"], got["checks"]
     assert got["metrics"]["engine_step_ms.serve_overload"]["value"] > 0
+
+
+@pytest.mark.parametrize("x64", [False, True], ids=["x32", "x64"])
+def test_one_copy_each_way_per_dispatch(x64):
+    with jax.enable_x64(x64):
+        engine, reqs = _serve()
+        engine.run()
+    assert all(r.done for r in reqs)
+    assert engine.h2d_copies == DISPATCHES
+    assert engine.d2h_copies == COLLECTS
