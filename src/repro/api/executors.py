@@ -34,6 +34,8 @@ from repro.core import solve as solve_lib
 from repro.core import streaming as streaming_lib
 from repro.engine import plan as plan_lib
 
+_span = jax.profiler.TraceAnnotation
+
 
 def spec_from_legacy(degree, *, method: str | None = None,
                      basis: str = basis_lib.MONOMIAL,
@@ -172,10 +174,28 @@ def fit(x: jax.Array, y: jax.Array, spec: FitSpec | None = None, *,
     two calls with equal specs share one executable, two different specs
     compile once each and then coexist (the serve no-recompile invariant,
     extended to the whole API).  DegreeSearch specs are eager at the top
-    like ``polyfit(..., "auto")`` always was."""
+    like ``polyfit(..., "auto")`` always was.
+
+    The host side of each call is the profiler span ``api.fit``; its
+    ``path`` is the plan's execution path for a fixed-degree LSE spec
+    (the raw-data solver's name for one that skips the moments), else
+    ``search``, ``irls`` or ``lspia``."""
     spec = FitSpec() if spec is None else spec
     x = jnp.asarray(x)
     y = jnp.asarray(y)
+    if spec.is_search:
+        path = "search"
+    elif spec.method != "lse":
+        path = spec.method
+    elif spec.numerics.solver in RAW_DATA_SOLVERS:
+        path = spec.numerics.solver
+    else:
+        path = spec.plan(x.shape, x.dtype, weighted=weights is not None).path
+    with _span("api.fit", path=path):
+        return _fit_any(x, y, weights, spec)
+
+
+def _fit_any(x, y, weights, spec: FitSpec) -> FitResult:
     if spec.is_search:
         return _fit_search(x, y, weights, spec)
     if spec.method == "irls":

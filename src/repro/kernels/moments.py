@@ -12,11 +12,14 @@ TPU-native adaptation of the paper's CUDA moment kernel (DESIGN.md §2):
 * Power rows are built by iterated multiply (no transcendental `pow`),
   matching the paper's "matricized" construction.
 
-Three kernels live here:
+Four kernels live here:
 
 ``moments_extended``          one series per (128, block_n) MXU tile (the
                               original layout; rows degree+2..127 are zero),
                               ROW_BLOCK series per grid step.
+``moments_flat``              the same tile for ONE (n,) series read in place:
+                              no weights stream when unweighted, no padded
+                              copy; the ragged last block is masked in-kernel.
 ``moments_packed_extended``   P = 128 // (degree+2) series per tile — the
                               packed layout below.
 ``fused_report_sums``         one streamed pass computing everything
@@ -163,6 +166,20 @@ def _power_rows(x, y, degree):
     return jnp.stack(rows, axis=0)
 
 
+def _plain_tile_update(x, y, w, degree: int, accum_dtype, precision):
+    """One series' (128, 128) Gram contribution of a (block_n,) slice."""
+    # Build W rows by the iterated-multiply power ladder (paper's trick).
+    wmat = _power_rows(x, y, degree)                         # (deg+2, bn)
+    pad = K_PAD - (degree + 2)
+    if pad:
+        wmat = jnp.concatenate(
+            [wmat, jnp.zeros((pad, wmat.shape[1]), accum_dtype)], axis=0)
+    # MXU: (128, bn) @ (bn, 128), f32 accumulation; one side weighted.
+    return jax.lax.dot_general(
+        wmat * w, wmat, (((1,), (1,)), ((), ())),
+        precision=precision, preferred_element_type=accum_dtype)
+
+
 def _moments_kernel(x_ref, y_ref, w_ref, g_ref, *maybe_c, degree: int,
                     accum_dtype, precision):
     """One (row-block, n-block) grid step: G[r] += (W_r·w_r) W_rᵀ for each
@@ -174,20 +191,33 @@ def _moments_kernel(x_ref, y_ref, w_ref, g_ref, *maybe_c, degree: int,
     x = x_ref[...].astype(accum_dtype)   # (rb, block_n)
     y = y_ref[...].astype(accum_dtype)
     w = w_ref[...].astype(accum_dtype)
-
-    updates = []
-    for r in range(x.shape[0]):
-        # Build W rows by the iterated-multiply power ladder (paper's trick).
-        wmat = _power_rows(x[r], y[r], degree)               # (deg+2, bn)
-        pad = K_PAD - (degree + 2)
-        if pad:
-            wmat = jnp.concatenate(
-                [wmat, jnp.zeros((pad, wmat.shape[1]), accum_dtype)], axis=0)
-        # MXU: (128, bn) @ (bn, 128), f32 accumulation; one side weighted.
-        updates.append(jax.lax.dot_general(
-            wmat * w[r], wmat, (((1,), (1,)), ((), ())),
-            precision=precision, preferred_element_type=accum_dtype))
+    updates = [_plain_tile_update(x[r], y[r], w[r], degree, accum_dtype,
+                                  precision) for r in range(x.shape[0])]
     _accum_add(jnp.stack(updates), g_ref, c_ref)
+
+
+def _moments_flat_kernel(*refs, n: int, degree: int, weighted: bool,
+                         accum_dtype, precision):
+    """One n-block of a lone series: G += (W·w) Wᵀ over the block's points
+    below n.  The ragged last block reads past the array's end; those
+    lanes hold whatever the buffer held, so x, y and w are zeroed there
+    (a select, not a product: 0 · NaN is NaN)."""
+    x_ref, y_ref = refs[:2]
+    w_ref = refs[2] if weighted else None
+    g_ref, *maybe_c = refs[2 + weighted:]
+    c_ref = maybe_c[0] if maybe_c else None
+    i = pl.program_id(0)
+    _accum_init(i, (g_ref,) + ((c_ref,) if c_ref is not None else ()))
+
+    bn = x_ref.shape[0]
+    live = jax.lax.broadcasted_iota(jnp.int32, (bn,), 0) < n - i * bn
+    zero = jnp.zeros((), accum_dtype)
+    x = jnp.where(live, x_ref[...].astype(accum_dtype), zero)
+    y = jnp.where(live, y_ref[...].astype(accum_dtype), zero)
+    w = (live.astype(accum_dtype) if w_ref is None
+         else jnp.where(live, w_ref[...].astype(accum_dtype), zero))
+    update = _plain_tile_update(x, y, w, degree, accum_dtype, precision)
+    _accum_add(update[None], g_ref, c_ref)
 
 
 def _packed_tile_update(x, y, w, degree: int, accum_dtype, precision):
@@ -322,18 +352,19 @@ def _fused_report_kernel(x_ref, y_ref, w_ref, coef_ref, o_ref, *, degree: int,
 
 
 def _moments_call(kernel_fn, grid, in_specs, out_spec, b_out, *,
-                  compensated, accum_dtype, interpret, args):
+                  compensated, accum_dtype, interpret, args, name=None):
     """Shared pallas_call plumbing for the plain/packed moment kernels."""
     struct = jax.ShapeDtypeStruct((b_out, K_PAD, K_PAD), accum_dtype)
     if compensated:
         out = pl.pallas_call(
             kernel_fn, grid=grid, in_specs=in_specs,
             out_specs=[out_spec, out_spec], out_shape=[struct, struct],
-            interpret=interpret)(*args)
+            interpret=interpret, name=name)(*args)
         return out[0]   # Kahan: the corrected sum is the primary tile
     return pl.pallas_call(
         kernel_fn, grid=grid, in_specs=in_specs,
-        out_specs=out_spec, out_shape=struct, interpret=interpret)(*args)
+        out_specs=out_spec, out_shape=struct, interpret=interpret,
+        name=name)(*args)
 
 
 @functools.partial(jax.jit,
@@ -368,6 +399,44 @@ def moments_extended(x: jax.Array, y: jax.Array, weights: jax.Array, *,
                          out_spec, b, compensated=compensated,
                          accum_dtype=accum_dtype, interpret=interpret,
                          args=(x, y, weights))
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("degree", "block_n", "interpret",
+                                    "accum_dtype", "compensated"))
+def moments_flat(x: jax.Array, y: jax.Array, weights: jax.Array | None = None,
+                 *, degree: int, block_n: int = DEFAULT_BLOCK_N,
+                 accum_dtype=jnp.float32,
+                 compensated: bool = False,
+                 interpret: bool = False) -> jax.Array:
+    """Raw kernel output: the (K_PAD, K_PAD) extended Gram of one series.
+
+    x, y and weights (None: unweighted, and no weights stream is read) are
+    the caller's (n,) arrays at any n, read in place: the grid covers
+    cdiv(n, block_n) blocks and the kernel masks the points past n, so
+    nothing is padded, copied or relaid out.  The pallas_call is named
+    ``moments_plain``, which is how a device trace shows it.
+    """
+    if x.ndim != 1 or y.shape != x.shape or (
+            weights is not None and weights.shape != x.shape):
+        raise ValueError("moments_flat expects equal (n,) inputs")
+    if degree + 2 > K_PAD:
+        raise ValueError(f"degree {degree} too large for K_PAD={K_PAD}")
+    n = x.shape[0]
+    if n >= 1 << 31:
+        raise ValueError(f"n={n}: the kernel counts points in int32")
+    weighted = weights is not None
+    kernel_fn = functools.partial(_moments_flat_kernel, n=n, degree=degree,
+                                  weighted=weighted, accum_dtype=accum_dtype,
+                                  precision=gram_precision(n))
+    in_spec = pl.BlockSpec((block_n,), lambda ni: (ni,))
+    out_spec = pl.BlockSpec((1, K_PAD, K_PAD), lambda ni: (0, 0, 0))
+    args = (x, y) + ((weights,) if weighted else ())
+    return _moments_call(kernel_fn, (pl.cdiv(n, block_n),),
+                         [in_spec] * len(args), out_spec, 1,
+                         compensated=compensated, accum_dtype=accum_dtype,
+                         interpret=interpret, args=args,
+                         name="moments_plain")[0]
 
 
 @functools.partial(jax.jit,

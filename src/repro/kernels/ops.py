@@ -3,7 +3,9 @@
 Handles: batch/flat shapes, tail padding (weight-masked so padding is inert),
 block size choice, CPU fallback (interpret mode), packed-vs-plain path
 selection, and extraction of the ``Moments`` sufficient statistics from the
-kernels' extended Gram output.
+kernels' extended Gram output.  A lone (n,) series on the plain layout is
+not padded: ``kernel.moments_flat`` reads the caller's arrays in place, so
+a series may fill the device's memory (no ones array, no padded copies).
 
 Path selection (``moments(..., packing="auto")``):
   * **packed** — batch of ≥ 2 series and packing_factor(degree) ≥ 2: pack
@@ -60,11 +62,24 @@ def _pad_rows(arrs, multiple):
     return [jnp.pad(a, zpad) for a in arrs]
 
 
-def _true_count(weights, b, n, dtype):
+def _true_count(weights, batch, n, dtype):
     """Number of contributing points per series (not Σw — see module doc)."""
     if weights is None:
-        return jnp.full((b,), n, dtype)
+        return jnp.full(batch, n, dtype)
     return jnp.sum((weights != 0).astype(dtype), axis=-1)
+
+
+def _weight_sum(weights, batch, n, dtype):
+    if weights is None:
+        return jnp.full(batch, n, dtype)
+    return jnp.sum(weights, axis=-1).astype(dtype)
+
+
+def _from_gram(g, degree, count, weight_sum):
+    """``Moments`` from the (..., K_PAD, K_PAD) extended Gram."""
+    m1 = degree + 1
+    return Moments(gram=g[..., :m1, :m1], vty=g[..., :m1, m1],
+                   yty=g[..., m1, m1], count=count, weight_sum=weight_sum)
 
 
 @functools.partial(jax.jit, static_argnames=("degree", "block_n", "interpret",
@@ -96,15 +111,7 @@ def moments(x: jax.Array, y: jax.Array, degree: int, *,
     if accum_dtype is None:
         accum_dtype = jnp.float32
     flat = x.ndim == 1
-    if flat:
-        x, y = x[None], y[None]
-        if weights is not None:
-            weights = weights[None]
-    b, n = x.shape
-    count = _true_count(weights, b, n, accum_dtype)
-    weight_sum = (jnp.full((b,), n, accum_dtype) if weights is None
-                  else jnp.sum(weights, axis=-1).astype(accum_dtype))
-
+    b, n = (1,) + x.shape if flat else x.shape
     pfac = kernel.packing_factor(degree)
     use_packed = (packing == "packed"
                   or (packing == "auto" and b > 1 and pfac > 1))
@@ -115,9 +122,24 @@ def moments(x: jax.Array, y: jax.Array, degree: int, *,
         raise ValueError("nbuf (multi-buffered DMA pipeline) is a packed-"
                          "kernel knob; this call resolved to the plain "
                          "layout")
-
     if block_n is None:
         block_n = _auto_block(n)
+
+    if flat and not use_packed:
+        # a lone series on the plain layout: the kernel reads the caller's
+        # arrays in place, at any n
+        g = kernel.moments_flat(x, y, weights, degree=degree,
+                                block_n=block_n, accum_dtype=accum_dtype,
+                                compensated=compensated, interpret=interpret)
+        return _from_gram(g, degree, _true_count(weights, (), n, accum_dtype),
+                          _weight_sum(weights, (), n, accum_dtype))
+    if flat:
+        x, y = x[None], y[None]
+        if weights is not None:
+            weights = weights[None]
+    count = _true_count(weights, (b,), n, accum_dtype)
+    weight_sum = _weight_sum(weights, (b,), n, accum_dtype)
+
     w = jnp.ones_like(x) if weights is None else weights
     x, y, w = _pad_tail([x, y, w], (-n) % block_n)
     # zero weight ⇒ padded tail contributes nothing
@@ -137,9 +159,7 @@ def moments(x: jax.Array, y: jax.Array, degree: int, *,
                                     accum_dtype=accum_dtype,
                                     compensated=compensated,
                                     interpret=interpret)[:b]
-    m1 = degree + 1
-    out = Moments(gram=g[:, :m1, :m1], vty=g[:, :m1, m1],
-                  yty=g[:, m1, m1], count=count, weight_sum=weight_sum)
+    out = _from_gram(g, degree, count, weight_sum)
     if flat:
         out = jax.tree.map(lambda a: a[0], out)
     return out

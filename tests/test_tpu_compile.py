@@ -38,10 +38,14 @@ def one_chip():
     jax.config.update("jax_enable_compilation_cache", was)
 
 
-def _compile_text(fn, shapes, sharding):
+def _compile(fn, shapes, sharding):
     args = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=sharding)
             for s in shapes]
-    return jax.jit(fn).lower(*args).compile().as_text()
+    return jax.jit(fn).lower(*args).compile()
+
+
+def _compile_text(fn, shapes, sharding):
+    return _compile(fn, shapes, sharding).as_text()
 
 
 @pytest.mark.parametrize("compensated", [False, True])
@@ -52,6 +56,36 @@ def test_plain_single_series_large(one_chip, compensated):
                           compensated=compensated, interpret=False),
         [(n,), (n,)], one_chip)
     assert "tpu_custom_call" in text
+
+
+# a series of 1e9 f32 points: 8 GB of x and y, half of the chip's 16 GB
+RESIDENT_POINTS = 1_000_000_000
+IN_PLACE_TEMP_BYTES = 64 << 20
+
+
+def test_plain_single_series_resident_in_place(one_chip):
+    """The lone-series plain moment pass reads a resident series in place:
+    no ones array, no padded copies, at a length no block divides."""
+    compiled = _compile(
+        functools.partial(ops.moments, degree=3, packing="plain",
+                          interpret=False),
+        [(RESIDENT_POINTS,), (RESIDENT_POINTS,)], one_chip)
+    assert compiled.memory_analysis().temp_size_in_bytes < IN_PLACE_TEMP_BYTES
+    assert "moments_plain" in compiled.as_text()
+
+
+def test_resident_fit_program_in_place(one_chip, monkeypatch):
+    """The whole fixed-degree fit (domain, moments, count, solve, report)
+    of a resident 1e9-point series holds no copy of it either."""
+    from repro import api
+    from repro.api import executors
+    monkeypatch.setattr(ops, "_should_interpret", lambda: False)
+    arg = jax.ShapeDtypeStruct((RESIDENT_POINTS,), jnp.float32,
+                               sharding=one_chip)
+    spec = api.FitSpec(degree=3, engine="kernel_plain")
+    compiled = executors._fit_lse_fixed.lower(arg, arg, None, spec).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < IN_PLACE_TEMP_BYTES
+    assert "moments_plain" in compiled.as_text()
 
 
 @pytest.mark.parametrize("degree", [3, 5])
