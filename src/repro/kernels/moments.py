@@ -2,10 +2,11 @@
 
 TPU-native adaptation of the paper's CUDA moment kernel (DESIGN.md §2):
 
-* The paper's per-thread partial power sums become a *single MXU matmul* per
-  data tile. With W = [V | y] (rows = powers of x, then y), the product
-  G = (W ⊙ w) Wᵀ simultaneously yields the Hankel/Gram matrix, the moment
-  vector Vᵀy, Σwy² and Σw — every sufficient statistic of the fit.
+* For batches, the paper's per-thread partial power sums become a *single
+  MXU matmul* per data tile. With W = [V | y] (rows = powers of x, then
+  y), the product G = (W ⊙ w) Wᵀ simultaneously yields the Hankel/Gram
+  matrix, the moment vector Vᵀy, Σwy² and Σw — every sufficient statistic
+  of the fit.
 * Grid streams (batch, n-block) tiles HBM→VMEM; the (128, 128) accumulator
   tile stays VMEM-resident across the n-block grid dimension (constant
   index_map), mirroring the shared-memory block reduction on GPU.
@@ -17,9 +18,16 @@ Four kernels live here:
 ``moments_extended``          one series per (128, block_n) MXU tile (the
                               original layout; rows degree+2..127 are zero),
                               ROW_BLOCK series per grid step.
-``moments_flat``              the same tile for ONE (n,) series read in place:
-                              no weights stream when unweighted, no padded
-                              copy; the ragged last block is masked in-kernel.
+``moments_flat``              ONE (n,) series read in place, with no matmul:
+                              the 3·degree+3 power sums Σw·x^k, Σw·x^k·y and
+                              Σw·y² as VPU products over FLAT_BLOCK_N-point
+                              blocks, one (8, 128) vreg of 1,024 live points
+                              at a time; the Hankel Gram A[j, k] = S_{j+k} is
+                              assembled from them.  No weights stream when
+                              unweighted, no padded copy; only the ragged
+                              last block is masked.  A 128-row MXU tile would
+                              spend 2·128² FLOP a point on its degree+2 live
+                              rows (25× the work at degree 3).
 ``moments_packed_extended``   P = 128 // (degree+2) series per tile — the
                               packed layout below.
 ``fused_report_sums``         one streamed pass computing everything
@@ -57,8 +65,9 @@ VMEM footprint of the packed tile (f32 accumulate, block_n = 4096):
   block_n for the compensated path if other buffers share the core.
 
 Path selection (see ``ops.moments``): packed when the batch has ≥ 2 series
-and P ≥ 2 (i.e. degree ≤ 62); plain for single series or huge degrees; the
-pure-jnp ``core.gram_moments`` remains the non-kernel reference path.
+and P ≥ 2 (i.e. degree ≤ 62); plain for single series or huge degrees, a
+lone (n,) series on ``moments_flat``; the pure-jnp ``core.gram_moments``
+remains the non-kernel reference path.
 
 Compensated accumulation
 ------------------------
@@ -67,8 +76,8 @@ exactly the large-n scale the paper targets. ``compensated=True`` keeps a
 second VMEM-resident tile carrying a Kahan running-error term: each block's
 contribution is corrected by the error of the previous addition, making the
 cross-block reduction error O(1) in the number of blocks instead of O(nblk).
-Costs one extra (128, 128) tile and 3 extra VPU adds per block — invisible
-next to the MXU matmul.
+Costs one extra accumulator tile and 3 extra VPU adds per block — invisible
+next to the block's own work.
 
 Double buffering (``nbuf >= 2``)
 --------------------------------
@@ -88,18 +97,28 @@ asserted in tests. Pick ``block_n`` with ``repro.kernels.tune``
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 K_PAD = 128          # fixed row count: degree + 2 <= 128
 DEFAULT_BLOCK_N = 4096
+# The lone-series pass (moments_flat) walks blocks of FLAT_BLOCK_N points
+# (1 MB of f32 a stream, double-buffered) one SLAB, one f32 vreg of
+# 8 × 128 points, at a time, FLAT_UNROLL slabs a loop iteration.
+FLAT_BLOCK_N = 1 << 18
+SLAB_SHAPE = (8, 128)
+SLAB = SLAB_SHAPE[0] * SLAB_SHAPE[1]
+FLAT_UNROLL = 8
 # series per grid step of the plain and fused-report kernels: a data block's
 # second-to-last dim must be a multiple of 8 or the full batch (TPU tiling)
 ROW_BLOCK = 8
-# Precision of the f32 Gram products on the MXU.  DEFAULT rounds both
+# Precision of the f32 Gram products on the MXU (the batched and packed
+# kernels; moments_flat multiplies in f32 on the VPU).  DEFAULT rounds both
 # operands to bf16 once, and the error that leaves in a fit shrinks as the
 # series grows; HIGHEST (six bf16 passes) is f32-accurate at about five times
 # the MXU time.  Excess SSE over the f64 least-squares fit, degree 3 on TPU
@@ -114,6 +133,11 @@ SUM_W, SUM_Y, SUM_YY, SUM_F, SUM_FF, SUM_YF, SUM_SSE, N_SUMS = range(8)
 def packing_factor(degree: int) -> int:
     """How many independent series fit in one 128-sublane tile."""
     return K_PAD // (degree + 2)
+
+
+def flat_sums(degree: int) -> int:
+    """Power sums of the lone-series pass: S_0..S_2d, T_0..T_d, Σwy²."""
+    return 3 * degree + 3
 
 
 def gram_precision(n: int) -> jax.lax.Precision:
@@ -196,28 +220,82 @@ def _moments_kernel(x_ref, y_ref, w_ref, g_ref, *maybe_c, degree: int,
     _accum_add(jnp.stack(updates), g_ref, c_ref)
 
 
+def _slab_terms(x, y, w, degree: int):
+    """One slab's terms of the lone-series power sums, in the order
+    ``moments_flat`` indexes them: w·x^k for k = 0..2·degree, w·x^k·y for
+    k = 0..degree, then w·y².  The weights are the ladder's base;
+    unweighted (w None) the base is 1, and Σw, which is n, has no term
+    (None)."""
+    s = [w]
+    for _ in range(2 * degree):
+        s.append(x if s[-1] is None else s[-1] * x)
+    wy = y if w is None else w * y
+    t = [wy] + [s[k] * y for k in range(1, degree + 1)]
+    return s + t + [wy * y]
+
+
 def _moments_flat_kernel(*refs, n: int, degree: int, weighted: bool,
-                         accum_dtype, precision):
-    """One n-block of a lone series: G += (W·w) Wᵀ over the block's points
-    below n.  The ragged last block reads past the array's end; those
-    lanes hold whatever the buffer held, so x, y and w are zeroed there
-    (a select, not a product: 0 · NaN is NaN)."""
+                         accum_dtype):
+    """One n-block of a lone series: the 3·degree+3 power sums of its
+    points below n, added to (8, 128) lane partials that stay in VMEM
+    across the grid.  The block is walked one (8, 128) slab (one f32
+    vreg, 1,024 points) at a time, so every product is a VPU op on live
+    points.  The ragged last block reads past the array's end; on that
+    step alone x, y and w are zeroed there (a select, not a product:
+    0 · NaN is NaN)."""
     x_ref, y_ref = refs[:2]
     w_ref = refs[2] if weighted else None
-    g_ref, *maybe_c = refs[2 + weighted:]
+    s_ref, *maybe_c = refs[2 + weighted:]
     c_ref = maybe_c[0] if maybe_c else None
     i = pl.program_id(0)
-    _accum_init(i, (g_ref,) + ((c_ref,) if c_ref is not None else ()))
+    _accum_init(i, (s_ref,) + ((c_ref,) if c_ref is not None else ()))
 
     bn = x_ref.shape[0]
-    live = jax.lax.broadcasted_iota(jnp.int32, (bn,), 0) < n - i * bn
+    n_slabs = bn // SLAB
+    unroll = math.gcd(n_slabs, FLAT_UNROLL)
+    lane_index = (jax.lax.broadcasted_iota(jnp.int32, SLAB_SHAPE, 0)
+                  * SLAB_SHAPE[1]
+                  + jax.lax.broadcasted_iota(jnp.int32, SLAB_SHAPE, 1))
     zero = jnp.zeros((), accum_dtype)
-    x = jnp.where(live, x_ref[...].astype(accum_dtype), zero)
-    y = jnp.where(live, y_ref[...].astype(accum_dtype), zero)
-    w = (live.astype(accum_dtype) if w_ref is None
-         else jnp.where(live, w_ref[...].astype(accum_dtype), zero))
-    update = _plain_tile_update(x, y, w, degree, accum_dtype, precision)
-    _accum_add(update[None], g_ref, c_ref)
+
+    def slab(ref, r):
+        start = pl.multiple_of(r * SLAB, SLAB)
+        return ref[pl.ds(start, SLAB)].astype(accum_dtype).reshape(SLAB_SHAPE)
+
+    def block_sums(masked: bool):
+        def add_slab(r, acc):
+            x, y = slab(x_ref, r), slab(y_ref, r)
+            w = None if w_ref is None else slab(w_ref, r)
+            if masked:
+                live = lane_index < n - i * bn - r * SLAB
+                x = jnp.where(live, x, zero)
+                y = jnp.where(live, y, zero)
+                w = None if w is None else jnp.where(live, w, zero)
+            return tuple(a if t is None else a + t
+                         for a, t in zip(acc, _slab_terms(x, y, w, degree)))
+
+        def add_slabs(g, acc):
+            for k in range(unroll):
+                acc = add_slab(g * unroll + k, acc)
+            return acc
+
+        init = tuple(jnp.zeros(SLAB_SHAPE, accum_dtype)
+                     for _ in range(flat_sums(degree)))
+        acc = jax.lax.fori_loop(0, n_slabs // unroll, add_slabs, init)
+        _accum_add(jnp.stack(acc), s_ref, c_ref)
+
+    if n % bn == 0:
+        block_sums(masked=False)
+        return
+    last = pl.num_programs(0) - 1
+
+    @pl.when(i < last)
+    def _full():
+        block_sums(masked=False)
+
+    @pl.when(i == last)
+    def _ragged():
+        block_sums(masked=True)
 
 
 def _packed_tile_update(x, y, w, degree: int, accum_dtype, precision):
@@ -401,20 +479,29 @@ def moments_extended(x: jax.Array, y: jax.Array, weights: jax.Array, *,
                          args=(x, y, weights))
 
 
+def flat_block(n: int) -> int:
+    """Points a grid step of the lone-series pass: FLAT_BLOCK_N, or n
+    rounded up to whole slabs when that is less."""
+    return min(FLAT_BLOCK_N, -(-n // SLAB) * SLAB)
+
+
 @functools.partial(jax.jit,
                    static_argnames=("degree", "block_n", "interpret",
                                     "accum_dtype", "compensated"))
 def moments_flat(x: jax.Array, y: jax.Array, weights: jax.Array | None = None,
-                 *, degree: int, block_n: int = DEFAULT_BLOCK_N,
+                 *, degree: int, block_n: int | None = None,
                  accum_dtype=jnp.float32,
                  compensated: bool = False,
                  interpret: bool = False) -> jax.Array:
-    """Raw kernel output: the (K_PAD, K_PAD) extended Gram of one series.
+    """The (degree+2, degree+2) extended Gram of one series:
+    [[A, Vᵀy], [yᵀV, Σwy²]] with the Hankel A[j, k] = S_{j+k}.
 
     x, y and weights (None: unweighted, and no weights stream is read) are
     the caller's (n,) arrays at any n, read in place: the grid covers
-    cdiv(n, block_n) blocks and the kernel masks the points past n, so
-    nothing is padded, copied or relaid out.  The pallas_call is named
+    cdiv(n, block_n) blocks (``flat_block(n)`` by default; a caller's
+    block_n must be a multiple of SLAB) and the kernel masks the points
+    past n, so nothing is padded, copied or relaid out.  The sums are VPU
+    products in accum_dtype; no matmul runs.  The pallas_call is named
     ``moments_plain``, which is how a device trace shows it.
     """
     if x.ndim != 1 or y.shape != x.shape or (
@@ -425,18 +512,30 @@ def moments_flat(x: jax.Array, y: jax.Array, weights: jax.Array | None = None,
     n = x.shape[0]
     if n >= 1 << 31:
         raise ValueError(f"n={n}: the kernel counts points in int32")
+    if block_n is None:
+        block_n = flat_block(n)
+    if block_n % SLAB:
+        raise ValueError(f"block_n={block_n} must be a multiple of {SLAB}")
     weighted = weights is not None
     kernel_fn = functools.partial(_moments_flat_kernel, n=n, degree=degree,
-                                  weighted=weighted, accum_dtype=accum_dtype,
-                                  precision=gram_precision(n))
-    in_spec = pl.BlockSpec((block_n,), lambda ni: (ni,))
-    out_spec = pl.BlockSpec((1, K_PAD, K_PAD), lambda ni: (0, 0, 0))
+                                  weighted=weighted, accum_dtype=accum_dtype)
     args = (x, y) + ((weights,) if weighted else ())
-    return _moments_call(kernel_fn, (pl.cdiv(n, block_n),),
-                         [in_spec] * len(args), out_spec, 1,
-                         compensated=compensated, accum_dtype=accum_dtype,
-                         interpret=interpret, args=args,
-                         name="moments_plain")[0]
+    partials = (flat_sums(degree),) + SLAB_SHAPE
+    lane_sums = pl.pallas_call(
+        kernel_fn, grid=(pl.cdiv(n, block_n),),
+        in_specs=[pl.BlockSpec((block_n,), lambda ni: (ni,))] * len(args),
+        out_specs=pl.BlockSpec(partials, lambda ni: (0, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct(partials, accum_dtype),
+        scratch_shapes=([pltpu.VMEM(partials, accum_dtype)] if compensated
+                        else []),
+        interpret=interpret, name="moments_plain")(*args)
+    s = lane_sums.sum(axis=(1, 2))
+    if not weighted:
+        s = s.at[0].set(n)
+    j = np.arange(degree + 1)
+    hankel = s[j[:, None] + j[None, :]]
+    vty = s[2 * degree + 1 + j]
+    return jnp.block([[hankel, vty[:, None]], [vty[None, :], s[-1:, None]]])
 
 
 @functools.partial(jax.jit,

@@ -5,7 +5,8 @@ block size choice, CPU fallback (interpret mode), packed-vs-plain path
 selection, and extraction of the ``Moments`` sufficient statistics from the
 kernels' extended Gram output.  A lone (n,) series on the plain layout is
 not padded: ``kernel.moments_flat`` reads the caller's arrays in place, so
-a series may fill the device's memory (no ones array, no padded copies).
+a series may fill the device's memory (no ones array, no padded copies),
+and sums its powers on the VPU with no matmul.
 
 Path selection (``moments(..., packing="auto")``):
   * **packed** — batch of ≥ 2 series and packing_factor(degree) ≥ 2: pack
@@ -13,7 +14,8 @@ Path selection (``moments(..., packing="auto")``):
     the layout diagram in ``repro.kernels.moments``). Batches not divisible
     by P are padded with zero-weight tail series whose exact-zero Gram
     blocks are sliced away.
-  * **plain** — single series, or degree > 62 (P < 2): one series per tile.
+  * **plain** — single series, or degree > 62 (P < 2): one series per tile;
+    a lone (n,) series takes ``kernel.moments_flat``'s power sums instead.
   * the pure-jnp path stays in ``repro.core.gram_moments`` (the
     ``repro.engine`` plan layer picks between them; ``engine="reference"``
     forces it).
@@ -76,7 +78,7 @@ def _weight_sum(weights, batch, n, dtype):
 
 
 def _from_gram(g, degree, count, weight_sum):
-    """``Moments`` from the (..., K_PAD, K_PAD) extended Gram."""
+    """``Moments`` from the (..., K, K) extended Gram, K >= degree+2."""
     m1 = degree + 1
     return Moments(gram=g[..., :m1, :m1], vty=g[..., :m1, m1],
                    yty=g[..., m1, m1], count=count, weight_sum=weight_sum)
@@ -122,17 +124,16 @@ def moments(x: jax.Array, y: jax.Array, degree: int, *,
         raise ValueError("nbuf (multi-buffered DMA pipeline) is a packed-"
                          "kernel knob; this call resolved to the plain "
                          "layout")
-    if block_n is None:
-        block_n = _auto_block(n)
-
     if flat and not use_packed:
         # a lone series on the plain layout: the kernel reads the caller's
-        # arrays in place, at any n
+        # arrays in place, at any n, in blocks derived from n
         g = kernel.moments_flat(x, y, weights, degree=degree,
                                 block_n=block_n, accum_dtype=accum_dtype,
                                 compensated=compensated, interpret=interpret)
         return _from_gram(g, degree, _true_count(weights, (), n, accum_dtype),
                           _weight_sum(weights, (), n, accum_dtype))
+    if block_n is None:
+        block_n = _auto_block(n)
     if flat:
         x, y = x[None], y[None]
         if weights is not None:

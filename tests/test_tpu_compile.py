@@ -74,6 +74,26 @@ def test_plain_single_series_resident_in_place(one_chip):
     assert "moments_plain" in compiled.as_text()
 
 
+@pytest.mark.parametrize("degree, compensated, weighted",
+                         [(3, True, False), (9, False, False),
+                          (3, False, True)],
+                         ids=["compensated", "degree9", "weighted"])
+def test_plain_single_series_resident_variants(one_chip, degree, compensated,
+                                               weighted):
+    """The lone-series pass's other variants hold its VMEM budget and its
+    in-place read at the resident size too."""
+    def fit_moments(x, y, *w):
+        return ops.moments(x, y, degree, weights=w[0] if w else None,
+                           packing="plain", compensated=compensated,
+                           interpret=False)
+
+    compiled = _compile(fit_moments,
+                        [(RESIDENT_POINTS,)] * (3 if weighted else 2),
+                        one_chip)
+    assert compiled.memory_analysis().temp_size_in_bytes < IN_PLACE_TEMP_BYTES
+    assert "moments_plain" in compiled.as_text()
+
+
 def test_resident_fit_program_in_place(one_chip, monkeypatch):
     """The whole fixed-degree fit (domain, moments, count, solve, report)
     of a resident 1e9-point series holds no copy of it either."""
