@@ -1,12 +1,7 @@
-"""Render benchmark JSON into the EXPERIMENTS.md markdown tables.
+"""Render the committed BENCH_*.json files into the perf-trajectory table
+of EXPERIMENTS.md (row x rev):
 
-Two modes:
-
-    # the dry-run roofline grid (launch.dryrun output)
-    PYTHONPATH=src python -m benchmarks.make_tables dryrun_results.json
-
-    # the perf trajectory: row x rev from every committed BENCH_*.json
-    PYTHONPATH=src python -m benchmarks.make_tables --trajectory [--mode smoke]
+    PYTHONPATH=src python -m benchmarks.make_tables [--mode smoke]
 
 The trajectory table is the history the perf gate's budgets are anchored
 to: one column per benchmarked revision (git order), us/call per cell,
@@ -21,42 +16,8 @@ import json
 import os
 import re
 import subprocess
-import sys
 
 BENCH_DIR = os.path.dirname(os.path.abspath(__file__))
-
-
-# ------------------------------------------------------------ dryrun tables
-def fmt_table(rows, mesh):
-    out = [
-        f"### Mesh {mesh}",
-        "",
-        "| arch | shape | compute_s | memory_s | collective_s | dominant | "
-        "step_s | peak GB/dev | MODEL_FLOPs/HLO_FLOPs | tokens/step |",
-        "|---|---|---|---|---|---|---|---|---|---|",
-    ]
-    for r in rows:
-        out.append(
-            f"| {r['arch']} | {r['shape']} | {r['compute_s']:.4f} | "
-            f"{r['memory_s']:.4f} | {r['collective_s']:.4f} | "
-            f"{r['dominant']} | {r['step_s']:.4f} | "
-            f"{r['peak_memory_gb']:.2f} | {r['useful_flops_ratio']:.3f} | "
-            f"{r['tokens_per_step']:,} |")
-    return "\n".join(out)
-
-
-def dryrun_tables(path):
-    rs = [r for r in json.load(open(path)) if r.get("status") == "ok"]
-    for mesh in ("16x16", "2x16x16"):
-        rows = [r for r in rs if r["mesh"] == mesh]
-        print(fmt_table(rows, mesh))
-        print()
-    bad = [r for r in json.load(open(path)) if r.get("status") != "ok"]
-    if bad:
-        print("### FAILED CELLS")
-        for r in bad:
-            print(f"- {r['arch']} × {r['shape']} × {r['mesh']}: "
-                  f"{r.get('error')}")
 
 
 # -------------------------------------------------------- trajectory tables
@@ -166,19 +127,12 @@ def trajectory_table(runs):
 
 def main():
     ap = argparse.ArgumentParser(description=__doc__)
-    ap.add_argument("path", nargs="?", default=None,
-                    help="dryrun_results.json (dryrun-table mode)")
-    ap.add_argument("--trajectory", action="store_true",
-                    help="render the row x rev perf-trajectory table")
     ap.add_argument("--mode", default="smoke",
                     help="BENCH file suffix to aggregate (default: smoke)")
     args = ap.parse_args()
-    if args.trajectory:
-        print(f"### Perf trajectory ({args.mode})")
-        print()
-        print(trajectory_table(load_trajectory(args.mode)))
-    else:
-        dryrun_tables(args.path or "dryrun_results.json")
+    print(f"### Perf trajectory ({args.mode})")
+    print()
+    print(trajectory_table(load_trajectory(args.mode)))
 
 
 if __name__ == "__main__":

@@ -704,45 +704,10 @@ def bench_obs_overhead(quick: bool):
             f"({dt_on * 1e3:.1f}ms vs {dt_null * 1e3:.1f}ms)")
 
 
-def bench_e2e_train(quick: bool):
-    """Smoke-scale end-to-end train step (framework overhead check).
-    derived = tokens/s on this CPU host."""
-    from repro import configs
-    from repro.models import get_model
-    from repro.train import TrainConfig, init_train_state, make_train_step
-    cfg = configs.get_smoke_config("internlm2-1.8b")
-    model = get_model(cfg)
-    state = init_train_state(model, jax.random.PRNGKey(0))
-    step = jax.jit(make_train_step(model, TrainConfig()))
-    b, s = 4, 128
-    batch = {"tokens": jnp.ones((b, s), jnp.int32),
-             "labels": jnp.ones((b, s), jnp.int32),
-             "loss_mask": jnp.ones((b, s), jnp.float32)}
-    state, _ = step(state, batch)  # compile
-
-    def run(state):
-        state, m = step(state, batch)
-        return state, m
-
-    best = float("inf")
-    reps = 2
-    iters = 5 if quick else 20
-    for _ in range(reps):
-        t0 = time.perf_counter()
-        for _ in range(iters):
-            state, m = run(state)
-        jax.block_until_ready(m["loss"])
-        best = min(best, (time.perf_counter() - t0) / iters * 1e6)
-    us = Timed(best, {"stat": "min_of_reps", "reps": reps, "iters": iters,
-                      "warmup": 1})
-    row("train_step_smoke", us, f"{b * s / (us / 1e6):.0f}tok/s")
-
-
 BENCHES = [bench_accuracy, bench_speedup, bench_kernel, bench_kernel_packed,
            bench_fused_report, bench_solver_stack, bench_select,
            bench_streaming, bench_batched_fits, bench_api_dispatch,
-           bench_serve_fit, bench_serve_fleet, bench_obs_overhead,
-           bench_e2e_train]
+           bench_serve_fit, bench_serve_fleet, bench_obs_overhead]
 
 
 def _git_rev() -> str:

@@ -1,3 +1,3 @@
-from repro.data.pipeline import DataConfig, TokenPipeline, curve_dataset
+from repro.data.pipeline import curve_dataset
 
-__all__ = ["DataConfig", "TokenPipeline", "curve_dataset"]
+__all__ = ["curve_dataset"]

@@ -1,77 +1,8 @@
-"""Deterministic, per-host-sharded synthetic data pipeline.
-
-Every host generates only its own shard of each global batch from a seeded
-counter (no cross-host I/O): batch `i`, host `h` derives its examples from
-fold_in(seed, i * n_hosts + h). Restart-safe (the batch index is part of the
-checkpoint) and elastic-safe (resharding only changes the host→example map,
-not the example stream).
-
-Token streams follow a Zipfian unigram draw with a Markov low-rank structure
-so models actually learn (loss decreases) in the end-to-end examples.
-"""
+"""Synthetic data for the paper's own workload: seeded polynomial series."""
 from __future__ import annotations
 
-import dataclasses
-
-import jax
 import jax.numpy as jnp
 import numpy as np
-
-
-@dataclasses.dataclass(frozen=True)
-class DataConfig:
-    vocab_size: int
-    seq_len: int
-    global_batch: int
-    seed: int = 0
-    zipf_a: float = 1.2
-
-
-class TokenPipeline:
-    """iter(batches) of {'tokens','labels','loss_mask'} for one host."""
-
-    def __init__(self, dcfg: DataConfig, host_id: int = 0, n_hosts: int = 1,
-                 start_batch: int = 0):
-        assert dcfg.global_batch % n_hosts == 0
-        self.dcfg = dcfg
-        self.host_id = host_id
-        self.n_hosts = n_hosts
-        self.batch_idx = start_batch
-        self.local_batch = dcfg.global_batch // n_hosts
-        # Zipf-ish unigram over vocab, fixed by seed
-        ranks = np.arange(1, dcfg.vocab_size + 1, dtype=np.float64)
-        probs = ranks ** (-dcfg.zipf_a)
-        self._logits = jnp.asarray(np.log(probs / probs.sum()), jnp.float32)
-
-    def _rng(self):
-        key = jax.random.PRNGKey(self.dcfg.seed)
-        return jax.random.fold_in(
-            key, self.batch_idx * self.n_hosts + self.host_id)
-
-    def next(self):
-        d = self.dcfg
-        rng = self._rng()
-        r1, r2 = jax.random.split(rng)
-        base = jax.random.categorical(
-            r1, self._logits, shape=(self.local_batch, d.seq_len + 1))
-        # Markov structure: with p=0.5 the next token repeats (t + 1) mod V
-        rep = jax.random.bernoulli(r2, 0.5,
-                                   (self.local_batch, d.seq_len + 1))
-        toks = jnp.where(
-            rep, jnp.roll((base + 1) % d.vocab_size, 1, axis=1), base)
-        self.batch_idx += 1
-        return {
-            "tokens": toks[:, :-1].astype(jnp.int32),
-            "labels": toks[:, 1:].astype(jnp.int32),
-            "loss_mask": jnp.ones((self.local_batch, d.seq_len),
-                                  jnp.float32),
-        }
-
-    def state(self) -> dict:
-        return {"batch_idx": self.batch_idx}
-
-    def restore(self, state: dict) -> None:
-        self.batch_idx = int(state["batch_idx"])
 
 
 def curve_dataset(n: int, degree: int = 3, noise: float = 1.0,

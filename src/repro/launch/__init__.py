@@ -1,2 +1,2 @@
-"""Launch layer: mesh construction, multi-pod dry-run, roofline analysis,
-training and serving drivers."""
+"""Launch layer: mesh construction, roofline analysis, the serving entry
+point, the perf gate and the compile cache."""

@@ -139,10 +139,3 @@ def analyze(compiled) -> Roofline:
         peak_memory=int(peak),
     )
 
-
-def model_flops(cfg, shape, n_tokens: int) -> float:
-    """Useful-model FLOPs for the step: 6·N·D train, 2·N·D decode/prefill
-    (N = active params)."""
-    n_active = cfg.active_param_count()
-    mult = 6.0 if shape.kind == "train" else 2.0
-    return mult * n_active * n_tokens

@@ -9,18 +9,12 @@ fault injection, parity check against the fault-free run):
 
     PYTHONPATH=src python -m repro.launch.serve --workload fleet \
         --workers 4 --chaos "crash=1,stall=1,poison=1" --assert-parity
-
-Token serving (the zoo-arch decode engine):
-
-    PYTHONPATH=src python -m repro.launch.serve --workload tokens \
-        --arch internlm2-1.8b --smoke
 """
 from __future__ import annotations
 
 import argparse
 import time
 
-import jax
 import numpy as np
 
 
@@ -197,52 +191,16 @@ def _obs_finish(args, fleet, reqs) -> None:
     print(fleet.metrics.render_prometheus(), end="")
 
 
-def serve_tokens(args) -> None:
-    from repro import configs
-    from repro.models import get_model
-    from repro.serve import EngineConfig, ServeEngine
-
-    cfg = (configs.get_smoke_config(args.arch) if args.smoke
-           else configs.get_config(args.arch))
-    model = get_model(cfg)
-    params = model.init_params(jax.random.PRNGKey(0))
-    engine = ServeEngine(model, params,
-                         EngineConfig(n_slots=args.slots,
-                                      max_len=args.max_len))
-
-    rng = jax.random.PRNGKey(7)
-    reqs = []
-    for i in range(args.requests):
-        rng, sub = jax.random.split(rng)
-        prompt = [int(t) for t in
-                  jax.random.randint(sub, (8 + i % 8,), 3,
-                                     cfg.vocab_size - 1)]
-        reqs.append(engine.submit(prompt, max_new_tokens=args.max_new,
-                                  temperature=0.8))
-    t0 = time.time()
-    engine.run()
-    dt = time.time() - t0
-    done = sum(r.done for r in reqs)
-    toks = sum(len(r.out_tokens) for r in reqs)
-    print(f"[serve] {done}/{len(reqs)} finished, {toks} tokens "
-          f"in {dt:.1f}s ({toks/dt:.1f} tok/s)")
-    for r in reqs[:3]:
-        print(f"  req {r.uid}: {len(r.out_tokens)} tokens "
-              f"{r.out_tokens[:10]}...")
-    assert done == len(reqs)
-
-
 def main(argv=None):
     """Run one serving workload; ``--workload fits`` returns its finished
     requests."""
     from repro.launch.compile_cache import use_compile_cache
 
     ap = argparse.ArgumentParser()
-    ap.add_argument("--workload", choices=("fits", "fleet", "tokens"),
-                    default="fits")
-    # per-workload defaults: fits churns cheap requests, tokens decodes
+    ap.add_argument("--workload", choices=("fits", "fleet"), default="fits")
+    # per-workload default: fits churns cheap requests, fleet replays few
     ap.add_argument("--requests", type=int, default=None)
-    ap.add_argument("--slots", type=int, default=None)
+    ap.add_argument("--slots", type=int, default=8)
     ap.add_argument("--seed", type=int, default=7,
                     help="seed of the generated fit requests")
     # fit-serving knobs
@@ -273,24 +231,13 @@ def main(argv=None):
     ap.add_argument("--slo-p99", type=float, default=200.0,
                     help="latency p99 SLO threshold (ticks) the SLO "
                          "monitor forecasts breaches against")
-    # token-serving knobs
-    ap.add_argument("--arch", default="internlm2-1.8b")
-    ap.add_argument("--smoke", action="store_true")
-    ap.add_argument("--max-len", type=int, default=128)
-    ap.add_argument("--max-new", type=int, default=24)
     args = ap.parse_args(argv)
     use_compile_cache()
     if args.workload == "fits":
         args.requests = 200 if args.requests is None else args.requests
-        args.slots = 8 if args.slots is None else args.slots
         return serve_fits(args)
-    elif args.workload == "fleet":
-        args.requests = 32 if args.requests is None else args.requests
-        serve_fleet(args)
-    else:
-        args.requests = 12 if args.requests is None else args.requests
-        args.slots = 4 if args.slots is None else args.slots
-        serve_tokens(args)
+    args.requests = 32 if args.requests is None else args.requests
+    serve_fleet(args)
 
 
 if __name__ == "__main__":

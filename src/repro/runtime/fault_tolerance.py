@@ -1,7 +1,6 @@
 """Fault-tolerant training runtime: heartbeats, failure detection, restart
 policy, elastic rescale. The control plane is deliberately dependency-free
-(files/host callbacks) so it can sit on any cluster scheduler; the data plane
-(checkpoint restore, mesh rebuild) reuses repro.checkpoint and launch.mesh.
+(files/host callbacks) so it can sit on any cluster scheduler.
 
 What large-scale runs get from this module:
   * HeartbeatTracker  — per-host liveness with configurable timeout

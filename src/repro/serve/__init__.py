@@ -1,19 +1,14 @@
 """Serving engines: continuous batching over fixed slot pools.
 
 ``fit_engine`` serves the paper's workload — matricized LSE curve fits —
-and is the flagship path; ``fleet`` replicates it behind a fault-tolerant
-dispatcher (retry/hedging, moment-journal replay, graceful degradation);
-``engine`` is the token-decode engine the slot-pool design was first
-built around.
+in one synchronous process; ``fleet`` replicates it behind a
+fault-tolerant dispatcher (retry/hedging, moment-journal replay, graceful
+degradation).
 """
-from repro.serve.engine import ServeEngine, EngineConfig, Request
 from repro.serve.fit_engine import (FitServeEngine, FitServeConfig,
                                     FitRequest)
 from repro.serve.fleet import (FitFleet, FleetConfig, FleetRequest,
                                FleetWorker)
-from repro.serve.sampling import sample
 
-__all__ = ["ServeEngine", "EngineConfig", "Request",
-           "FitServeEngine", "FitServeConfig", "FitRequest",
-           "FitFleet", "FleetConfig", "FleetRequest", "FleetWorker",
-           "sample"]
+__all__ = ["FitServeEngine", "FitServeConfig", "FitRequest",
+           "FitFleet", "FleetConfig", "FleetRequest", "FleetWorker"]

@@ -1,9 +1,7 @@
 """Continuous-batching fit server: the paper's workload as a service.
 
-The token engine next door (``serve.engine``) batches decode steps over a
-fixed slot pool; this engine does the same for *curve fits* — the workload
-this repo actually reproduces.  Ragged per-request (x, y) series arrive,
-are bucketed by length onto fixed-width slot pools, and ingest through the
+Ragged per-request (x, y) series arrive, are bucketed by length onto
+fixed-width slot pools, and ingest through the
 matricized moment accumulator (packed P-series-per-tile Pallas kernel on
 TPU, via ``repro.engine`` plan dispatch) with per-slot streaming
 ``StreamState`` — so a million-point series occupies one slot and folds in

@@ -1,11 +1,3 @@
-from repro.train.optimizer import AdamWConfig, init_state, apply_updates, schedule
-from repro.train.train_step import (TrainConfig, make_train_step,
-                                    make_eval_step, init_train_state,
-                                    abstract_train_state, train_state_specs,
-                                    cross_entropy)
 from repro.train.monitors import LossCurveMonitor, StepTimeMonitor
 
-__all__ = ["AdamWConfig", "TrainConfig", "make_train_step", "make_eval_step",
-           "init_train_state", "abstract_train_state", "train_state_specs",
-           "cross_entropy", "LossCurveMonitor", "StepTimeMonitor",
-           "init_state", "apply_updates", "schedule"]
+__all__ = ["LossCurveMonitor", "StepTimeMonitor"]

@@ -22,6 +22,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro import core
+from repro.kernels.moments import FLAT_BLOCK_N, SLAB
 
 
 DEGREES = list(range(1, 10))
@@ -42,24 +43,34 @@ def _data(seed: int, n: int, degree: int, noise: float = 0.02,
     return x, y
 
 
-def _np_fit_values(x: np.ndarray, y: np.ndarray, degree: int) -> np.ndarray:
+def _np_polyfit(x, y, degree, weights=None):
+    # numpy weights multiply the residuals, ours multiply their squares
+    w = None if weights is None else np.sqrt(weights.astype(np.float64))
+    return np.polyfit(x.astype(np.float64), y.astype(np.float64), degree,
+                      w=w)
+
+
+def _np_fit_values(x: np.ndarray, y: np.ndarray, degree: int,
+                   weights: np.ndarray | None = None) -> np.ndarray:
     """Golden fitted values: numpy.polyfit in float64."""
-    c = np.polyfit(x.astype(np.float64), y.astype(np.float64), degree)
+    c = _np_polyfit(x, y, degree, weights)
     return np.polyval(c, x.astype(np.float64))
 
 
-def _np_coeffs(x: np.ndarray, y: np.ndarray, degree: int) -> np.ndarray:
-    return np.polyfit(x.astype(np.float64), y.astype(np.float64),
-                      degree)[::-1].copy()
+def _np_coeffs(x: np.ndarray, y: np.ndarray, degree: int,
+               weights: np.ndarray | None = None) -> np.ndarray:
+    return _np_polyfit(x, y, degree, weights)[::-1].copy()
 
 
 def _check_against_numpy(x: np.ndarray, y: np.ndarray, degree: int,
                          dtype, *, basis: str, normalize: bool,
-                         engine: str = "reference") -> None:
+                         engine: str = "reference",
+                         weights: np.ndarray | None = None) -> None:
     xj = jnp.asarray(x, dtype)
     yj = jnp.asarray(y, dtype)
-    poly = core.polyfit(xj, yj, degree, basis=basis, normalize=normalize,
-                        engine=engine)
+    wj = None if weights is None else jnp.asarray(weights, dtype)
+    poly = core.polyfit(xj, yj, degree, weights=wj, basis=basis,
+                        normalize=normalize, engine=engine)
     assert poly.diagnostics is not None
     cond = float(poly.diagnostics.condition)
     assert np.isfinite(cond) and cond >= 1.0
@@ -68,7 +79,7 @@ def _check_against_numpy(x: np.ndarray, y: np.ndarray, degree: int,
     # value space: both fits minimize the same Σe², so fitted values agree
     # to ~eps·√κ(Gram) relative (κ(V) = √κ(VᵀV)) — scaled by the measured
     # condition estimate, floored at a few ulps of the value scale
-    gold = _np_fit_values(x, y, degree)
+    gold = _np_fit_values(x, y, degree, weights)
     ours = np.asarray(poly(xj), np.float64)
     scale = float(np.linalg.norm(gold)) + 1e-30
     rel_gap = float(np.linalg.norm(ours - gold)) / scale
@@ -81,7 +92,7 @@ def _check_against_numpy(x: np.ndarray, y: np.ndarray, degree: int,
     # digits to compare — the honest part of "tolerances scaled by κ"
     pred_rel = 100.0 * eps * cond
     if basis == core.MONOMIAL and pred_rel < 1e-2:
-        gold_c = _np_coeffs(x, y, degree)
+        gold_c = _np_coeffs(x, y, degree, weights)
         ours_c = np.asarray(poly.monomial_coeffs(), np.float64)
         rel_c = (np.linalg.norm(ours_c - gold_c)
                  / (np.linalg.norm(gold_c) + 1e-30))
@@ -108,14 +119,46 @@ def test_conformance_float64(degree):
                                      basis=basis, normalize=normalize)
 
 
-@pytest.mark.parametrize("degree", [1, 2, 3, 5, 7, 9])
-def test_conformance_kernel_engines(degree):
-    """The Pallas paths (plain + packed, interpret mode off-TPU) conform to
-    the same numpy gold as the reference path (monomial/f32 — the kernels'
-    domain)."""
-    x, y = _data(200 + degree, 256, degree)
+KERNEL_DEGREES = [1, 2, 3, 5, 7, 9]
+# Lone-series lengths at the edges of the plain kernel's blocking
+# (kernels.moments.moments_flat sums one SLAB at a time over blocks of up
+# to FLAT_BLOCK_N points, masking the ragged end in-kernel).  The short
+# ones run at every degree: part of one slab, a series that only just
+# determines the fit, and one point either side of a slab.
+_SHORT = {"n256": lambda d: 256, "d_plus_1": lambda d: d + 1,
+          "slab_minus_1": lambda d: SLAB - 1,
+          "slab_plus_1": lambda d: SLAB + 1}
+# The long ones, one point either side of a block and a length no slab
+# divides over three blocks, stream up to half a million points through
+# interpret-mode Pallas, so they run at the large-series cell's degree
+# (3) only.
+_LONG = {"block_minus_1": FLAT_BLOCK_N - 1, "block_plus_1": FLAT_BLOCK_N + 1,
+         "three_blocks_ragged": 2 * FLAT_BLOCK_N + 3 * SLAB + 5}
+KERNEL_PLAIN_CASES = (
+    [pytest.param(d, n(d), id=f"{d}-{name}")
+     for name, n in _SHORT.items() for d in KERNEL_DEGREES]
+    + [pytest.param(3, n, id=f"3-{name}") for name, n in _LONG.items()])
+
+
+@pytest.mark.parametrize("weighted", [False, True],
+                         ids=["unweighted", "weighted"])
+@pytest.mark.parametrize("degree, n", KERNEL_PLAIN_CASES)
+def test_conformance_kernel_engines(degree, n, weighted):
+    """The lone-series Pallas path (plain layout, interpret mode off-TPU)
+    conforms to the same numpy gold as the reference path (monomial/f32 —
+    the kernels' domain) at every length its blocking can leave ragged;
+    a weighted fit streams the weights as a third array."""
+    x, y = _data(200 + degree, n, degree)
+    w = (np.random.default_rng(n).uniform(0.25, 2.0, n) if weighted
+         else None)
     _check_against_numpy(x, y, degree, jnp.float32, basis=core.MONOMIAL,
-                         normalize=True, engine="kernel_plain")
+                         normalize=True, engine="kernel_plain", weights=w)
+
+
+@pytest.mark.parametrize("degree", KERNEL_DEGREES)
+def test_conformance_packed_kernel_engine(degree):
+    """The packed Pallas path (a batch of series per tile) conforms to the
+    same numpy gold, series by series."""
     xb, yb = _data(300 + degree, 256, degree, batch=(3,))
     poly = core.polyfit(jnp.asarray(xb, jnp.float32),
                         jnp.asarray(yb, jnp.float32), degree,

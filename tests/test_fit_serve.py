@@ -1,5 +1,7 @@
 """Continuous-batching fit server: parity with direct polyfit on ragged
 traces, chunked ingest of long series, and the no-recompile invariant."""
+import dataclasses
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -30,13 +32,62 @@ def _assert_matches_polyfit(reqs: list[FitRequest], degree, atol=5e-4):
                                    err_msg=f"req {r.uid} n={r.n}")
 
 
-def test_ragged_trace_matches_direct_polyfit():
-    eng = FitServeEngine(FitServeConfig(degree=3, n_slots=4,
-                                        buckets=(64, 256), ridge=1e-9))
-    reqs = [eng.submit(x, y) for x, y in _trace(0, 25, 5, 700)]
+def _assert_matches_numpy(reqs: list[FitRequest], degree, atol=5e-4):
+    """Served coefficients against numpy's float64 least-squares fit of
+    the same float32 series."""
+    for r in reqs:
+        assert r.done and r.count == r.n
+        ref = np.polyfit(r.x.astype(np.float64), r.y.astype(np.float64),
+                         degree)[::-1]
+        np.testing.assert_allclose(r.coeffs, ref, rtol=5e-3, atol=atol,
+                                   err_msg=f"req {r.uid} n={r.n}")
+
+
+@pytest.fixture
+def small_server():
+    return FitServeEngine(FitServeConfig(degree=3, n_slots=4,
+                                         buckets=(64, 256), ridge=1e-9))
+
+
+@pytest.fixture(scope="module")
+def default_server():
+    """One server with the fit server's default settings (degree 3, 8
+    slots, buckets 256 and 2048), warmed once for the whole module."""
+    eng = FitServeEngine(FitServeConfig())
+    eng.warmup()
+    return eng
+
+
+# One request per length class at the edges of the default server: the
+# fewest points a degree-3 pool takes, one chunk of the small bucket, one
+# point under it, exactly it, one point over it (one chunk of the wide
+# bucket), exactly the wide bucket, one point over it (a 1-point tail),
+# and multi-chunk ingest ending in a full or a 1-point tail, up to the
+# 8192 points the served traffic reaches.
+DEFAULT_SERVER_LENGTHS = (4, 16, 255, 256, 257, 2047, 2048, 2049, 4096,
+                          4097, 6144, 8191, 8192)
+# (server fixture, _trace arguments: seed, requests, min and max length)
+RAGGED_TRACES = (
+    [pytest.param("small_server", (0, 25, 5, 700), 3, id="ragged_mix")]
+    + [pytest.param("default_server", (n, 1, n, n), d, id=f"n{n}-deg{d}")
+       for n in DEFAULT_SERVER_LENGTHS for d in (1, 2, 3)])
+
+
+@pytest.mark.parametrize("server, trace, degree", RAGGED_TRACES)
+def test_ragged_trace_matches_direct_polyfit(request, server, trace, degree):
+    """Served fits answer as numpy's float64 polyfit: a ragged mix over
+    both buckets of a small server, and one request of each length class
+    on the default server at its pool degree and at the nested degrees it
+    serves from the truncated moments."""
+    eng = request.getfixturevalue(server)
+    spec = (None if degree == eng.cfg.degree
+            else dataclasses.replace(eng.fixed_spec, degree=degree))
+    series = _trace(*trace, degree=degree)
+    done = eng.fits_done
+    reqs = [eng.submit(x, y, spec=spec) for x, y in series]
     eng.run()
-    assert eng.fits_done == 25
-    _assert_matches_polyfit(reqs, 3)
+    assert eng.fits_done - done == len(series)
+    _assert_matches_numpy(reqs, degree)
 
 
 def test_long_series_streams_through_small_bucket():

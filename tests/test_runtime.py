@@ -1,19 +1,12 @@
 """Fault-tolerance runtime: heartbeat detection, restart policy, elastic
-planning, straggler reslicing, chaos-injected detector behavior,
-serve-engine behavior, data-pipeline determinism/elasticity."""
-import jax
-import jax.numpy as jnp
+planning, straggler reslicing, chaos-injected detector behavior."""
 import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
-from repro import configs
-from repro.data import DataConfig, TokenPipeline
-from repro.models import get_model
 from repro.runtime import (ChaosSchedule, ChaosWorker, ElasticPlan,
                            FailureDetector, FaultEvent, HeartbeatTracker,
                            RestartPolicy, ResliceAction, plan_reslice)
-from repro.serve import EngineConfig, ServeEngine
 from repro.train.monitors import StepTimeMonitor
 
 
@@ -176,75 +169,3 @@ def test_failure_detector_flags_chaos_persistent_straggler():
                             resume_step=7)
     assert plan.mesh_shape == (2, 4)
     assert plan.resume_step == 7
-
-
-# ------------------------------------------------------------ data pipeline
-def test_pipeline_deterministic_and_host_sharded():
-    d = DataConfig(vocab_size=128, seq_len=16, global_batch=8)
-    a0 = TokenPipeline(d, host_id=0, n_hosts=2)
-    a1 = TokenPipeline(d, host_id=1, n_hosts=2)
-    full = TokenPipeline(d, host_id=0, n_hosts=1)
-    b0, b1, bf = a0.next(), a1.next(), full.next()
-    assert b0["tokens"].shape == (4, 16)
-    assert bf["tokens"].shape == (8, 16)
-    # host shards differ
-    assert not np.array_equal(np.asarray(b0["tokens"]),
-                              np.asarray(b1["tokens"]))
-    # restart determinism
-    a0b = TokenPipeline(d, host_id=0, n_hosts=2)
-    np.testing.assert_array_equal(np.asarray(a0b.next()["tokens"]),
-                                  np.asarray(b0["tokens"]))
-    # labels are shifted tokens
-    np.testing.assert_array_equal(np.asarray(b0["labels"][:, :-1]),
-                                  np.asarray(b0["tokens"][:, 1:]))
-
-
-def test_pipeline_state_roundtrip():
-    d = DataConfig(vocab_size=64, seq_len=8, global_batch=2)
-    p = TokenPipeline(d)
-    p.next(); p.next()
-    st = p.state()
-    want = p.next()
-    q = TokenPipeline(d)
-    q.restore(st)
-    got = q.next()
-    np.testing.assert_array_equal(np.asarray(got["tokens"]),
-                                  np.asarray(want["tokens"]))
-
-
-# ------------------------------------------------------------ serve engine
-def test_serve_engine_continuous_batching():
-    cfg = configs.get_smoke_config("internlm2-1.8b")
-    model = get_model(cfg)
-    params = model.init_params(jax.random.PRNGKey(0))
-    eng = ServeEngine(model, params, EngineConfig(n_slots=2, max_len=64))
-    reqs = [eng.submit([5, 6, 7], max_new_tokens=5) for _ in range(5)]
-    eng.run(max_steps=100)
-    assert all(r.done for r in reqs)
-    assert all(len(r.out_tokens) == 5 or
-               (r.out_tokens and r.out_tokens[-1] == eng.ecfg.eos_id)
-               for r in reqs)
-
-
-def test_serve_greedy_matches_decode_loop():
-    """Engine greedy output == hand-rolled prefill+decode loop."""
-    cfg = configs.get_smoke_config("yi-6b")
-    model = get_model(cfg)
-    params = model.init_params(jax.random.PRNGKey(0))
-    prompt = [5, 9, 13, 21]
-    n_new = 6
-
-    logits, state = model.prefill(params, {"tokens": jnp.asarray([prompt])},
-                                  64)
-    tok = jnp.argmax(logits[:, -1, :], -1).astype(jnp.int32)[:, None]
-    want = [int(tok[0, 0])]
-    for _ in range(n_new - 1):
-        logits, state = model.decode_step(params, tok, state)
-        tok = jnp.argmax(logits[:, -1, :], -1).astype(jnp.int32)[:, None]
-        want.append(int(tok[0, 0]))
-
-    eng = ServeEngine(model, params, EngineConfig(n_slots=1, max_len=64,
-                                                  eos_id=-1))
-    req = eng.submit(prompt, max_new_tokens=n_new, temperature=0.0)
-    eng.run(max_steps=50)
-    assert req.out_tokens == want

@@ -1,83 +1,5 @@
-"""Sharding rules (spec construction, dedupe, divisibility fallback) and
-roofline HLO-parsing units — no multi-device requirement."""
-import jax
-import numpy as np
-import pytest
-from jax.sharding import PartitionSpec as P
-
+"""Roofline HLO-parsing units — no multi-device requirement."""
 from repro.launch import roofline as roof
-from repro.launch.mesh import make_host_mesh
-from repro.sharding import rules
-
-
-class FakeMesh:
-    """Minimal mesh stand-in: axis names + sizes (no devices needed)."""
-
-    def __init__(self, shape: dict):
-        self.axis_names = tuple(shape)
-        self.shape = shape
-
-
-M = FakeMesh({"pod": 2, "data": 16, "model": 16})
-
-
-def test_spec_basic_mapping():
-    # FSDP ("embed") extends over pod+data when the pod axis exists
-    s = rules.spec_for(M, ("embed", "q_heads", "head_dim"))
-    assert s == P(("pod", "data"), "model", None)
-    s1 = rules.spec_for(FakeMesh({"data": 16, "model": 16}),
-                        ("embed", "q_heads", "head_dim"))
-    assert s1 == P("data", "model", None)
-
-
-def test_spec_dedupes_repeated_mesh_axis():
-    # zamba attn_out: both dims logical-map to the same mesh axes
-    s = rules.spec_for(M, ("embed", "embed"))
-    assert s == P(("pod", "data"), None)
-
-
-def test_spec_divisibility_drops_axis():
-    # yi-6b: 4 kv heads cannot shard over 16-way model axis
-    s = rules.spec_for(M, ("batch", "kv_seq", "kv_heads", "head_dim"),
-                       dims=(128, 32768, 4, 128))
-    assert s == P(("pod", "data"), None, None, None)
-
-
-def test_spec_batch_maps_to_all_data_axes():
-    s = rules.spec_for(M, ("batch", None, "vocab"))
-    assert s == P(("pod", "data"), None, "model")
-
-
-def test_decode_overrides_cache_layout():
-    r = dict(rules.BASE_RULES)
-    r.update(rules.DECODE_OVERRIDES)
-    # kv_seq stays local (in-place DUS); kv_heads take the TP axis
-    s = rules.spec_for(M, ("batch", "kv_seq", "kv_heads", "head_dim"),
-                       rules=r, dims=(128, 32768, 16, 128))
-    assert s == P(("pod", "data"), None, "model", None)
-    # heads that don't divide TP fall back to head_dim (qwen1.5 kv=20,
-    # GQA kv=8 on a 16-way axis)
-    s2 = rules.spec_for(M, ("batch", "kv_seq", "kv_heads", "head_dim"),
-                        rules=r, dims=(128, 32768, 20, 128))
-    assert s2 == P(("pod", "data"), None, None, "model")
-
-
-def test_long_context_overrides():
-    r = dict(rules.BASE_RULES)
-    r.update(rules.LONG_CONTEXT_OVERRIDES)
-    s = rules.spec_for(M, ("batch", "kv_seq", "kv_heads", "head_dim"),
-                       rules=r, dims=(1, 524288, 32, 224))
-    assert s == P(None, ("data", "model"), None, None)
-
-
-def test_tree_shardings_with_real_mesh():
-    mesh = make_host_mesh(data=1, model=1)
-    spec_tree = {"w": ("embed", "mlp"), "scalar": ()}
-    shape_tree = {"w": jax.ShapeDtypeStruct((64, 128), np.float32),
-                  "scalar": jax.ShapeDtypeStruct((), np.int32)}
-    out = rules.tree_shardings(mesh, spec_tree, shape_tree)
-    # 1-device mesh: axes exist but have size 1 ⇒ fully replicated
-    assert out["w"].is_fully_replicated
 
 
 # ------------------------------------------------------------ HLO parsing
@@ -118,16 +40,3 @@ def test_roofline_terms():
     assert abs(r.memory_s - 1.0) < 1e-9
     assert abs(r.collective_s - 1.0) < 1e-9
     assert r.step_s == max(r.compute_s, r.memory_s, r.collective_s)
-
-
-def test_model_flops():
-    from repro import configs
-    from repro.configs.base import SHAPES
-    cfg = configs.get_config("yi-6b")
-    mf = roof.model_flops(cfg, SHAPES["train_4k"], 1_048_576)
-    # yi-6b ≈ 6.06B params → 6·N·D ≈ 3.8e16
-    assert 2e16 < mf < 6e16
-    cfg_moe = configs.get_config("dbrx-132b")
-    act = cfg_moe.active_param_count()
-    tot = cfg_moe.param_count()
-    assert 0.2 < act / tot < 0.35   # 16 experts top-4 + attn + embed
