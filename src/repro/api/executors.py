@@ -20,6 +20,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro import engine as engine_lib
 from repro import select as select_lib
@@ -81,11 +82,26 @@ def _spec_domain(spec: FitSpec, x: jax.Array,
         else basis_lib.Domain.identity(x.dtype), dtype=x.dtype)
 
 
+def _data_only(poly: fit_lib.Polynomial, rep, spec: FitSpec,
+               normalize: bool):
+    """``(poly, rep)`` without the leaves the spec alone decides: a domain
+    not taken from the data (pinned, or the identity) and the report's
+    copy of the coefficients.  ``_fit_lse`` puts them back on the host."""
+    if spec.domain is not None or not normalize:
+        poly = dataclasses.replace(poly, domain_shift=None,
+                                   domain_scale=None)
+    if rep is not None:
+        rep = dataclasses.replace(rep, coeffs=None)
+    return poly, rep
+
+
 @partial(jax.jit, static_argnames=("spec",))
 def _fit_lse_fixed(x: jax.Array, y: jax.Array,
                    weights: jax.Array | None, spec: FitSpec):
     """The paper's pipeline for one fixed-degree LSE spec: plan → domain →
-    moments → condition-aware solve (+ the free moment-space report)."""
+    moments → condition-aware solve (+ the free moment-space report).
+
+    Returns only what the data decides (``_data_only``)."""
     degree = int(spec.degree)
     if spec.numerics.solver in RAW_DATA_SOLVERS:
         # the MATLAB-polyfit baseline: QR directly on the (weighted)
@@ -105,7 +121,7 @@ def _fit_lse_fixed(x: jax.Array, y: jax.Array,
         coeffs = solve_lib.qr_solve_vandermonde(v, yy)
         poly = fit_lib.Polynomial(coeffs=coeffs, domain_shift=dom.shift,
                                   domain_scale=dom.scale, basis=spec.basis)
-        return poly, None
+        return _data_only(poly, None, spec, spec.numerics.normalize)
     plan = spec.plan(x.shape, x.dtype, weighted=weights is not None)
     pol = plan.numerics
     dom = _spec_domain(spec, x, pol.normalize)
@@ -121,7 +137,93 @@ def _fit_lse_fixed(x: jax.Array, y: jax.Array,
         domain=dom, basis=spec.basis,
         normalized=pol.normalize or spec.domain is not None)
     rep = fit_lib.report_from_moments(m, poly.coeffs)
-    return poly, rep
+    return _data_only(poly, rep, spec, pol.normalize)
+
+
+# The host side of a fixed-degree LSE call: the span's path is resolved
+# once per (spec, shape, dtype, weighted), and on concrete one-device
+# arrays a spec-fixed domain is two device scalars made once per (dtype,
+# values, device) and shared by every answer, so that after the first read
+# their host values are cached on the arrays and a caller's copy back
+# skips them.
+_LSE_PATHS: dict = {}
+_DOMAIN_CONSTANTS: dict = {}
+_CACHE_ENTRIES = 4096
+_DISPATCH_COUNTER = {"calls": 0, "cached": 0, "static_domain": 0}
+
+
+def reset_dispatch_counter() -> None:
+    for k in _DISPATCH_COUNTER:
+        _DISPATCH_COUNTER[k] = 0
+
+
+def dispatch_counter() -> dict:
+    """Snapshot of the fixed-degree LSE dispatch counter: ``calls`` on
+    concrete one-device inputs, how many of them found their path
+    ``cached``, and how many had a ``static_domain`` (pinned or identity)
+    returned as the shared constants.  Traced calls count nothing."""
+    return dict(_DISPATCH_COUNTER)
+
+
+def _domain_constants(spec: FitSpec, dtype,
+                      sharding: jax.sharding.Sharding) -> basis_lib.Domain:
+    """The spec-fixed domain as device scalars on ``sharding``'s device,
+    uncommitted, so that they join computations on any device as the
+    program's own outputs would on uncommitted inputs."""
+    values = spec.domain or (0.0, 1.0)
+    key = (dtype, values, sharding)
+    dom = _DOMAIN_CONSTANTS.get(key)
+    if dom is None or dom.shift.is_deleted() or dom.scale.is_deleted():
+        if len(_DOMAIN_CONSTANTS) >= _CACHE_ENTRIES:
+            _DOMAIN_CONSTANTS.clear()
+        (device,) = sharding.device_set
+        with jax.default_device(device):
+            dom = basis_lib.Domain(
+                *(jax.device_put(np.asarray(v, dtype)) for v in values))
+        _DOMAIN_CONSTANTS[key] = dom
+    return dom
+
+
+def _on_one_device(a) -> bool:
+    return (isinstance(a, jax.Array) and not isinstance(a, jax.core.Tracer)
+            and isinstance(a.sharding, jax.sharding.SingleDeviceSharding))
+
+
+def _fit_lse(x: jax.Array, y: jax.Array, weights: jax.Array | None,
+             spec: FitSpec) -> FitResult:
+    """A fixed-degree LSE call: nothing but a dict lookup precedes the
+    enqueue once the path is cached.  The answer gets the spec-fixed domain
+    back as the shared constants on concrete one-device inputs, and as
+    fresh arrays in the caller's trace or placement otherwise."""
+    weighted = weights is not None
+    key = (spec, x.shape, x.dtype, weighted)
+    path = _LSE_PATHS.get(key)
+    cached = path is not None
+    if not cached:
+        if len(_LSE_PATHS) >= _CACHE_ENTRIES:
+            _LSE_PATHS.clear()
+        path = _LSE_PATHS[key] = (
+            spec.numerics.solver if spec.numerics.solver in RAW_DATA_SOLVERS
+            else spec.plan(x.shape, x.dtype, weighted=weighted).path)
+    with _span("api.fit", path=path):
+        poly, rep = _fit_lse_fixed(x, y, weights, spec)
+        static = poly.domain_shift is None
+        if (_on_one_device(x) and _on_one_device(y)
+                and not isinstance(weights, jax.core.Tracer)):
+            _DISPATCH_COUNTER["calls"] += 1
+            _DISPATCH_COUNTER["cached"] += cached
+            _DISPATCH_COUNTER["static_domain"] += static
+            if static:
+                dom = _domain_constants(spec, x.dtype, x.sharding)
+        elif static:
+            dom = spec.domain_or(basis_lib.Domain.identity(x.dtype),
+                                 dtype=x.dtype)
+        if static:
+            poly = dataclasses.replace(poly, domain_shift=dom.shift,
+                                       domain_scale=dom.scale)
+        if rep is not None:
+            rep = dataclasses.replace(rep, coeffs=poly.coeffs)
+        return FitResult(poly=poly, report=rep)
 
 
 def _fit_search(x: jax.Array, y: jax.Array,
@@ -179,35 +281,28 @@ def fit(x: jax.Array, y: jax.Array, spec: FitSpec | None = None, *,
     The host side of each call is the profiler span ``api.fit``; its
     ``path`` is the plan's execution path for a fixed-degree LSE spec
     (the raw-data solver's name for one that skips the moments), else
-    ``search``, ``irls`` or ``lspia``."""
+    ``search``, ``irls`` or ``lspia``.  A fixed-degree LSE call resolves
+    that path once per (spec, shape, dtype, weighted), and its answer
+    holds a domain the spec fixes as device constants shared by every
+    call (``dispatch_counter``)."""
     spec = FitSpec() if spec is None else spec
-    x = jnp.asarray(x)
-    y = jnp.asarray(y)
-    if spec.is_search:
-        path = "search"
-    elif spec.method != "lse":
-        path = spec.method
-    elif spec.numerics.solver in RAW_DATA_SOLVERS:
-        path = spec.numerics.solver
-    else:
-        path = spec.plan(x.shape, x.dtype, weighted=weights is not None).path
+    if not isinstance(x, jax.Array):
+        x = jnp.asarray(x)
+    if not isinstance(y, jax.Array):
+        y = jnp.asarray(y)
+    if not spec.is_search and spec.method == "lse":
+        return _fit_lse(x, y, weights, spec)
+    path = "search" if spec.is_search else spec.method
     with _span("api.fit", path=path):
-        return _fit_any(x, y, weights, spec)
-
-
-def _fit_any(x, y, weights, spec: FitSpec) -> FitResult:
-    if spec.is_search:
-        return _fit_search(x, y, weights, spec)
-    if spec.method == "irls":
-        rfit, _ = robust_lib.irls_fit(x, y, weights, spec)
-        return FitResult(poly=rfit.poly, iterations=rfit.iterations,
-                         converged=rfit.converged)
-    if spec.method == "lspia":
+        if spec.is_search:
+            return _fit_search(x, y, weights, spec)
+        if spec.method == "irls":
+            rfit, _ = robust_lib.irls_fit(x, y, weights, spec)
+            return FitResult(poly=rfit.poly, iterations=rfit.iterations,
+                             converged=rfit.converged)
         lf = lspia_lib.lspia_fit_spec(x, y, weights, None, spec)
         return FitResult(poly=lf.poly, iterations=lf.iterations,
                          converged=lf.converged)
-    poly, rep = _fit_lse_fixed(x, y, weights, spec)
-    return FitResult(poly=poly, report=rep)
 
 
 # ------------------------------------------------------------ streaming
