@@ -194,7 +194,14 @@ def _start_trace() -> str:
     return tmp
 
 
-def _read_trace(tmp: str):
+def plane_ordinals(devices) -> set[int]:
+    """The ``/device:TPU:<n>`` planes of ``devices`` in a profiler trace:
+    a TPU's ``n`` is its ``Device.id``; a CPU has no such plane."""
+    return {d.id for d in devices if d.platform == "tpu"}
+
+
+def _read_trace(tmp: str, devices):
+    """The trace under ``tmp``, reduced over ``devices`` alone."""
     from bench import trace_reduce
     try:
         paths = [os.path.join(d, f) for d, _, files in os.walk(tmp)
@@ -202,7 +209,8 @@ def _read_trace(tmp: str):
         if len(paths) != 1:
             raise RuntimeError(f"expected one .xplane.pb under {tmp}, "
                                f"found {len(paths)}")
-        return trace_reduce.reduce(trace_reduce.read(paths[0]))
+        return trace_reduce.reduce(trace_reduce.read(paths[0]),
+                                   plane_ordinals(devices))
     finally:
         shutil.rmtree(tmp, ignore_errors=True)
 
@@ -268,7 +276,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
         f"{k} {stats[k]}" for k in ("bytes_in_use", "peak_bytes_in_use",
                                     "bytes_reserved", "peak_bytes_reserved",
                                     "bytes_limit") if k in stats), file=err)
-    summary = _read_trace(tmp) if tmp is not None else None
+    summary = _read_trace(tmp, devices) if tmp is not None else None
     system.finish()
     checks = system.check()
     notes = system.notes()

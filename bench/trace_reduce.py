@@ -3,17 +3,20 @@ device and host numbers.
 
 What a TPU trace holds, as read by ``jax.profiler.ProfileData``:
 
-* one plane per chip, ``/device:TPU:<n>``; its line ``XLA Ops`` has one
-  event per HLO operation, named by the instruction's text
-  (``%moments_extended.1 = f32[...] custom-call(...)``), which may nest
-  (a ``while`` holds its body's operations);
+* one plane per chip the profiler saw, ``/device:TPU:<n>``, which on a
+  host of several chips may include chips the cell does not use; its
+  line ``XLA Ops`` has one event per HLO operation, named by the
+  instruction's text (``%moments_extended.1 = f32[...]
+  custom-call(...)``), which may nest (a ``while`` holds its body's
+  operations);
 * the plane ``/host:CPU``, whose lines are host threads; the benchmark's
   own ``TraceAnnotation`` spans, all named ``bench.*``, are on the
   thread that ran the window.
 
 Host and device events share one clock, in ns from the trace's start.
 The measured window is the host span ``bench.window``; every number is
-taken inside it.
+taken inside it, over the cell's own chips: a one-chip cell on a host of
+four reads like one on a host of one.
 """
 from __future__ import annotations
 
@@ -65,8 +68,8 @@ def op_label(event_name: str) -> tuple[str, bool]:
 
 
 def read(path: str) -> Trace:
-    """The device operations of every TPU and the host's ``bench.*``
-    spans."""
+    """The device operations of every TPU plane and the host's
+    ``bench.*`` spans."""
     from jax.profiler import ProfileData
     data = ProfileData.from_file(path)
     devices: dict[int, list[Op]] = {}
@@ -127,21 +130,23 @@ class Summary:
 
     @property
     def busy_s(self) -> float:
-        """Seconds in which an operation ran, averaged over the chips."""
+        """Seconds in which an operation ran, averaged over the cell's
+        chips."""
         if not self.devices:
             return 0.0
         return (sum(d.busy_ns for d in self.devices.values())
                 / len(self.devices) * 1e-9)
 
     def idle_share(self) -> float | None:
-        """1 − busy / window on the chip that was idle longest."""
+        """1 − busy / window on the cell's chip that was idle longest."""
         if not self.devices:
             return None
         busy = min(d.busy_ns for d in self.devices.values())
         return 1.0 - busy * 1e-9 / self.window_s
 
     def collective_share(self) -> float | None:
-        """Device time inside collective operations over busy time."""
+        """Device time inside collective operations over busy time, on
+        the cell's chips."""
         busy = sum(d.busy_ns for d in self.devices.values())
         if not busy:
             return None
@@ -153,7 +158,7 @@ class Summary:
 
     def top_ops(self) -> list[list]:
         """The device operations that took most time, in seconds per
-        chip."""
+        chip of the cell."""
         total: dict[str, float] = {}
         for d in self.devices.values():
             for k, v in d.op_ns.items():
@@ -163,9 +168,9 @@ class Summary:
         return [[k, v / n * 1e-9] for k, v in ranked]
 
     def idle_gaps(self) -> list[list]:
-        """The longest idle gaps of the idlest chip, each named by the
-        host span that overlaps it most (the innermost ``bench.*`` span
-        other than the window; ``bench.window`` where none does)."""
+        """The longest idle gaps of the cell's idlest chip, each named by
+        the host span that overlaps it most (the innermost ``bench.*``
+        span other than the window; ``bench.window`` where none does)."""
         if not self.devices:
             return []
         idlest = min(self.devices.values(), key=lambda d: d.busy_ns)
@@ -190,15 +195,29 @@ class Summary:
         return {"device_ops": self.top_ops(), "idle_gaps": self.idle_gaps()}
 
 
-def reduce(trace: Trace) -> Summary:
-    """Everything inside the ``bench.window`` span."""
+def reduce(trace: Trace, chips) -> Summary:
+    """Everything inside the ``bench.window`` span, on the chips whose
+    plane ordinals are ``chips``: the cell's own, none off the TPU.  The
+    host's other chips are left out; a cell's chip whose plane ran
+    nothing in the window is wholly idle, and one with no plane at all is
+    an error, since its busy time is unknown.  So is a trace in which no
+    chip of the cell ran anything in the window while another chip did:
+    the planes named are then not the cell's."""
     windows = [s for s in trace.spans if s.name == WINDOW_SPAN]
     if len(windows) != 1:
         raise ValueError(f"expected one {WINDOW_SPAN} span, found "
                          f"{len(windows)}")
+    chips = sorted(set(chips))
+    missing = [c for c in chips if c not in trace.devices]
+    if missing:
+        raise ValueError(
+            "the trace has no plane for the cell's chip(s) "
+            + ", ".join(f"/device:TPU:{c}" for c in missing)
+            + f" (it has TPU planes {sorted(trace.devices)})")
     w0, w1 = windows[0].start, windows[0].end
     devices = {}
-    for dev, ops in trace.devices.items():
+    for dev in chips:
+        ops = trace.devices[dev]
         clipped = [(max(o.start, w0), min(o.end, w1), o) for o in ops
                    if o.end > w0 and o.start < w1]
         busy = union((s, e) for s, e, _ in clipped)
@@ -211,5 +230,14 @@ def reduce(trace: Trace) -> Summary:
                 if edges[i + 1] > edges[i]]
         devices[dev] = DeviceSummary(_length(busy), _length(coll), op_ns,
                                      gaps)
+    if devices and not any(d.busy_ns for d in devices.values()):
+        ran = [p for p, ops in sorted(trace.devices.items())
+               if p not in devices
+               and any(o.end > w0 and o.start < w1 for o in ops)]
+        if ran:
+            raise ValueError(
+                "no chip of the cell ran anything in the window, but "
+                + ", ".join(f"/device:TPU:{p}" for p in ran)
+                + f" did: the cell's chips are not planes {chips}")
     spans = [s for s in trace.spans if s.end > w0 and s.start < w1]
     return Summary((w0, w1), devices, spans)

@@ -4,9 +4,16 @@ The benchmark's tests plant them on the CPU; on the chip, run one cell
 with one fault planted, at the cell's own size:
 
     python tests/bench/faults.py --workload large_fit \
-        --fault half_the_blocks --seeds 11 12 13 --seconds 5
+        --fault half_the_blocks --seeds 11 --seconds 5
 
-prints one result line per seed; each has to read ``"correct": false``.
+prints one result line per seed; each has to read ``"correct": false``
+(give ``large_fit`` one seed a process: three 1e9-point series and
+their float64 checks outgrow a 40 GiB host).
+With ``--small`` it runs the cell at the tests' size with the harness's
+look for a chip skipped (``tests/bench/_cells.run_small_on`` runs it so
+in a child process with several CPU devices); with no ``--fault`` the
+run is sound.  Each line is the run's result line with the workload,
+fault, seed and the count of devices JAX found (``host_devices``).
 """
 import argparse
 import dataclasses
@@ -90,36 +97,53 @@ FAULTS = {
 }
 
 
+def plant(mp, system: str, name: str) -> None:
+    """Plant the fault ``name`` that a cell of kind ``system`` can
+    have."""
+    faults = {f.__name__: f for f in FAULTS[system]}
+    if name not in faults:
+        raise KeyError(f"no fault {name!r} for {system} (have "
+                       f"{sorted(faults)})")
+    faults[name](mp)
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--workload", required=True)
-    ap.add_argument("--fault", required=True)
+    ap.add_argument("--fault", default=None)
     ap.add_argument("--seeds", type=int, nargs="+", required=True)
     ap.add_argument("--seconds", type=float, default=5.0)
+    ap.add_argument("--small", action="store_true",
+                    help="the tests' size, on whatever devices JAX has")
     args = ap.parse_args(argv)
 
     import jax
     import pytest
 
-    from _cells import cell as find_cell
+    from _cells import SECONDS, small_cell
     from bench import harness
-    cell = find_cell(args.workload)
-    plant = {f.__name__: f for f in FAULTS[cell.config["system"]]}[args.fault]
-    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
-        jax.config.update("jax_compilation_cache_dir",
-                          os.path.join(ROOT, ".jax_cache"))
+    if args.small:
+        cell, seconds = small_cell(args.workload), SECONDS
+    else:
+        cell, seconds = harness.resolve(args.workload), args.seconds
+        if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+            jax.config.update("jax_compilation_cache_dir",
+                              os.path.join(ROOT, ".jax_cache"))
     with pytest.MonkeyPatch.context() as mp:
-        plant(mp)
+        if args.fault is not None:
+            plant(mp, cell.config["system"], args.fault)
         for seed in args.seeds:
             out = io.StringIO()
-            rc = harness.run_cell(cell, seed, args.seconds, False,
-                                  process_start=time.perf_counter(), out=out)
+            rc = harness.run_cell(cell, seed, seconds, False,
+                                  process_start=time.perf_counter(),
+                                  require_chip=not args.small, out=out)
             if rc:
                 return rc
             got = json.loads(out.getvalue().strip().splitlines()[-1])
-            print(json.dumps({"workload": args.workload, "fault": args.fault,
-                              "seed": seed, "correct": got["correct"],
-                              "checks": got["checks"]}), flush=True)
+            print(json.dumps(dict(got, workload=args.workload,
+                                  fault=args.fault, seed=seed,
+                                  host_devices=len(jax.devices()))),
+                  flush=True)
     return 0
 
 

@@ -29,3 +29,16 @@ def test_the_control_computes_in_bfloat16():
     g = control.bf16_gram(x, 2 * x, 301, 3)
     assert g.dtype == jnp.bfloat16 and g.shape == (5, 5)
     assert float(g[0, 0]) != 301.0   # 301 is no bfloat16 number
+
+
+def test_the_float32_sums_control_rounds_only_its_operands():
+    import jax.numpy as jnp
+    import numpy as np
+    x = jnp.linspace(-1.0, 1.0, 301)
+    g = control.bf16_gram(x, 2 * x, 301, 3, jnp.float32)
+    assert g.dtype == jnp.float32 and g.shape == (5, 5)
+    assert float(g[0, 0]) == 301.0   # the count is exact in float32
+    xb = np.asarray(x.astype(jnp.bfloat16), np.float64)
+    a = np.stack([np.ones_like(xb), xb,
+                  np.asarray((x * x).astype(jnp.bfloat16), np.float64)])
+    assert np.allclose(np.asarray(g)[:3, :3], a @ a.T, rtol=1e-6, atol=1e-6)

@@ -1,5 +1,5 @@
-"""The benchmark finds each cell's files by name, and refuses to run
-without a chip."""
+"""The benchmark finds each cell's files by name, keeps to its rules on
+chips and layers, and refuses to run without a chip."""
 import json
 import os
 import pathlib
@@ -33,18 +33,72 @@ def test_every_cell_resolves_its_files_by_name(workload):
         assert m["moves"] in names
 
 
+# the layers of PERF.md's section 3, from the entry point to the chip
+LAYERS = {"entry", "plan", "kernels", "collective", "device"}
+
+
+def keep_to_the_rules(spec: dict, root: pathlib.Path) -> None:
+    """Raise AssertionError where ``spec`` breaks one of the rules its
+    cells and metrics keep to."""
+    cells = [c["name"] for c in spec["workloads"]]
+    layers = {m["layer"] for m in spec["per_layer"]}
+    assert layers <= LAYERS, layers - LAYERS
+    assert all(c["chips"] in (1, 4) for c in spec["workloads"])
+    four = sum(c["chips"] == 4 for c in spec["workloads"])
+    assert four <= max(1, len(cells) // 2), f"{four} cells of four chips"
+    used = {c["config"] for c in spec["workloads"]}
+    assert used == {c["name"] for c in spec["configs"]}
+    for m in spec["end_to_end"] + spec["per_layer"]:
+        assert set(m.get("workloads", cells)) <= set(cells)
+    e2e = {m["name"] for m in spec["end_to_end"]}
+    assert {m["moves"] for m in spec["per_layer"]} <= e2e
+    for c in spec["configs"]:
+        assert (root / c["file"]).is_file()
+
+
 def test_spec_keeps_to_its_own_rules():
-    layers = {m["layer"] for m in SPEC["per_layer"]}
-    assert layers == {"kernels", "device", "entry"}
-    assert all(c["chips"] == 1 for c in SPEC["workloads"])
-    used = {c["config"] for c in SPEC["workloads"]}
-    assert used == {c["name"] for c in SPEC["configs"]}
-    for m in SPEC["end_to_end"] + SPEC["per_layer"]:
-        assert set(m.get("workloads", CELLS)) <= set(CELLS)
-    e2e = {m["name"] for m in SPEC["end_to_end"]}
-    assert {m["moves"] for m in SPEC["per_layer"]} <= e2e
-    for c in SPEC["configs"]:
-        assert (ROOT / c["file"]).is_file()
+    keep_to_the_rules(SPEC, ROOT)
+
+
+def _with_a_sharded_cell(tmp_path) -> dict:
+    """The spec with a third cell, of four chips, and a metric of the
+    ``collective`` layer, its configuration's file under ``tmp_path``."""
+    spec = json.loads(json.dumps(SPEC))
+    file = "bench/configs/sharded-series.json"
+    (tmp_path / file).parent.mkdir(parents=True)
+    (tmp_path / file).write_text("{}")
+    for c in spec["configs"]:
+        (tmp_path / c["file"]).write_text("{}")
+    spec["configs"].append({"name": "sharded-series", "file": file})
+    spec["workloads"].append({"name": "sharded_fit",
+                              "config": "sharded-series",
+                              "traffic": "closed_loop", "chips": 4})
+    for m in spec["end_to_end"]:
+        if "workloads" in m:
+            m["workloads"].append("sharded_fit")
+    spec["per_layer"].append({
+        "name": "collective_share.sharded_fit", "unit": "%",
+        "better": "lower", "source": "device_trace", "layer": "collective",
+        "moves": "fits_per_s", "workloads": ["sharded_fit"]})
+    return spec
+
+
+def test_the_rules_take_a_four_chip_cell_and_a_collective_metric(tmp_path):
+    keep_to_the_rules(_with_a_sharded_cell(tmp_path), tmp_path)
+
+
+@pytest.mark.parametrize("breach", ["two_cells_of_four_chips",
+                                    "three_chips", "a_layer_not_listed"])
+def test_the_rules_refuse_a_spec_that_breaks_them(tmp_path, breach):
+    spec = _with_a_sharded_cell(tmp_path)
+    if breach == "two_cells_of_four_chips":
+        spec["workloads"][0]["chips"] = 4
+    elif breach == "three_chips":
+        spec["workloads"][-1]["chips"] = 3
+    else:
+        spec["per_layer"][-1]["layer"] = "network"
+    with pytest.raises(AssertionError):
+        keep_to_the_rules(spec, tmp_path)
 
 
 def test_a_new_cell_is_entries_only(tmp_path):
@@ -93,7 +147,9 @@ def test_without_the_program_it_fails_and_prints_no_result(tmp_path):
 
 
 def test_a_split_metric_is_read_by_its_quantity():
-    assert harness.metric_path("device_idle.fit").endswith(
+    assert harness.metric_path("fit_roofline.large_fit").endswith(
+        os.path.join("metrics", "fit_roofline.py"))
+    assert harness.metric_path("device_idle.serve_overload").endswith(
         os.path.join("metrics", "device_idle.py"))
-    assert harness.metric_path("fit_ms").endswith(
-        os.path.join("metrics", "fit_ms.py"))
+    assert harness.metric_path("setup_s").endswith(
+        os.path.join("metrics", "setup_s.py"))

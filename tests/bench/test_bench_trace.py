@@ -18,7 +18,7 @@ FIXTURE = pathlib.Path(__file__).parent / "fixtures"
 def recorded():
     meta = json.loads((FIXTURE / "tpu_small.json").read_text())
     trace = tr.read(str(FIXTURE / "tpu_small.xplane.pb"))
-    return meta, trace, tr.reduce(trace)
+    return meta, trace, tr.reduce(trace, {0})
 
 
 def test_the_recorded_trace_has_one_chip_and_the_benchmark_spans(recorded):
@@ -38,6 +38,23 @@ def test_busy_and_idle_partition_the_window(recorded):
     assert 0 < summary.busy_s < summary.window_s
     assert summary.idle_share() == pytest.approx(gaps / window_ns)
     assert summary.collective_share() == 0.0
+
+
+def test_the_recorded_readings_stay_as_they_were(recorded):
+    # the numbers the reduction gave this trace before it was scoped to a
+    # cell's chips: the trace has one chip, so nothing may move
+    _, _, summary = recorded
+    assert summary.busy_s == 0.0022603610000000002
+    assert summary.window_s == 0.008494400000000001
+    assert summary.idle_share() == 0.7338998634394425
+    assert summary.collective_share() == 0.0
+    assert summary.top_ops()[:3] == [
+        ["moments_extended (custom-call)", 0.002135764],
+        ["while (while)", 5.2897000000000005e-05],
+        ["broadcast_in_dim (broadcast)", 4.9179000000000005e-05]]
+    assert summary.idle_gaps()[:3] == [
+        ["bench.fit", 0.00220649], ["bench.fit", 0.002130491],
+        ["bench.fit", 0.0018772980000000001]]
 
 
 def test_the_kernel_leads_the_breakdown(recorded):
@@ -87,7 +104,7 @@ def test_the_idlest_chip_sets_the_idle_share():
                      op("psum (all-reduce)", True, 50, 90)]},
         spans=[tr.Span("bench.window", 0, 100),
                tr.Span("bench.fit", 0, 95)])
-    s = tr.reduce(trace)
+    s = tr.reduce(trace, {0, 1})
     assert s.busy_s == pytest.approx(90e-9)
     assert s.idle_share() == pytest.approx(0.1)
     assert s.collective_share() == pytest.approx(50 / 180)
@@ -97,4 +114,54 @@ def test_the_idlest_chip_sets_the_idle_share():
 
 def test_a_trace_without_its_window_is_refused():
     with pytest.raises(ValueError, match="bench.window"):
-        tr.reduce(tr.Trace(devices={}, spans=[]))
+        tr.reduce(tr.Trace(devices={}, spans=[]), ())
+
+
+def _host_of(planes):
+    """A made-up trace of a host with chips ``planes``, where only chip 0
+    ran operations; the window is 100 ns."""
+    op = tr.Op
+    ran = [op("fit (custom-call)", False, 10, 40),
+           op("solve (while)", False, 40, 50),
+           op("fit (custom-call)", False, 70, 90)]
+    return tr.Trace(devices={p: list(ran) if p == 0 else [] for p in planes},
+                    spans=[tr.Span("bench.window", 0, 100),
+                           tr.Span("bench.fit", 5, 55),
+                           tr.Span("bench.fit", 60, 95)])
+
+
+def test_a_one_chip_cell_on_a_host_of_four_reads_as_on_a_host_of_one():
+    alone = tr.reduce(_host_of([0]), {0})
+    among = tr.reduce(_host_of([0, 1, 2, 3]), {0})
+    assert list(among.devices) == [0]
+    assert among.busy_s == alone.busy_s == pytest.approx(60e-9)
+    assert among.idle_share() == alone.idle_share() == pytest.approx(0.4)
+    assert among.top_ops() == alone.top_ops()
+    assert among.idle_gaps() == alone.idle_gaps()
+    assert among.devices[0].gaps == alone.devices[0].gaps == [
+        (0, 10), (50, 70), (90, 100)]
+
+
+def test_a_cell_chip_that_ran_nothing_is_wholly_idle():
+    s = tr.reduce(_host_of([0, 1, 2, 3]), {0, 1, 2, 3})
+    assert s.busy_s == pytest.approx(60e-9 / 4)
+    assert s.idle_share() == 1.0
+    assert s.top_ops()[0] == ["fit (custom-call)", pytest.approx(50e-9 / 4)]
+    # the idle chip's one gap is the whole window, named by the span that
+    # overlaps it most
+    assert s.idle_gaps() == [["bench.fit", pytest.approx(100e-9)]]
+
+
+def test_a_cell_whose_chips_all_ran_nothing_while_another_ran_is_refused():
+    # the device-to-plane map named the wrong planes
+    with pytest.raises(ValueError, match="/device:TPU:0 did"):
+        tr.reduce(_host_of([0, 1, 2, 3]), {1})
+    with pytest.raises(ValueError, match="/device:TPU:0 did"):
+        tr.reduce(_host_of([0, 1, 2, 3]), {2, 3})
+
+
+def test_a_cell_chip_with_no_plane_is_refused():
+    with pytest.raises(ValueError, match="/device:TPU:4"):
+        tr.reduce(_host_of([0, 1, 2, 3]), {0, 4})
+    # off the TPU the cell has no chip planes to read
+    assert tr.reduce(_host_of([]), ()).idle_share() is None
